@@ -335,6 +335,8 @@ def run_model(name: str, eng, g, x, reps: int, check_bits: bool,
 
 def main(mode: str, out_path: str, seed: int, devices: int,
          conformance_out: str = None, remap: bool = True) -> None:
+    from repro.engine import enable_compile_cache
+    enable_compile_cache()
     import jax
     import jax.numpy as jnp
 

@@ -37,7 +37,8 @@ except ImportError:                     # module: python -m benchmarks.bench_liv
 
 from repro.core import graph as G  # noqa: E402
 from repro.core.passes.partition import PartitionConfig  # noqa: E402
-from repro.engine import Engine, InferenceRequest  # noqa: E402
+from repro.engine import (Engine, InferenceRequest,  # noqa: E402
+                          enable_compile_cache)
 from repro.livegraph import (GraphDelta, GraphVersionStore,  # noqa: E402
                              LiveGraphServer)
 from repro.runtime import Metrics, OverlayPool, ServeLoop  # noqa: E402
@@ -238,6 +239,7 @@ def run(smoke: bool, out_path: str, seed: int = 0) -> dict:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small graph + small deltas (CI gate)")

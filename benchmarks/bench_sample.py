@@ -43,7 +43,8 @@ except ImportError:                    # module: python -m benchmarks.bench_samp
 
 from repro.core import graph as G  # noqa: E402
 from repro.core.passes.partition import PartitionConfig  # noqa: E402
-from repro.engine import Engine, InferenceRequest  # noqa: E402
+from repro.engine import (Engine, InferenceRequest,  # noqa: E402
+                          enable_compile_cache)
 from repro.runtime.metrics import percentile  # noqa: E402
 from repro.sampling import SamplingService, TargetRequest  # noqa: E402
 from repro.sampling.sampler import sample_ego  # noqa: E402
@@ -182,6 +183,7 @@ def run(smoke: bool, n_requests: int, n_overlays: int, max_batch: int,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small graph + short stream (CI gate)")

@@ -38,7 +38,8 @@ except ImportError:                     # module: python -m benchmarks.bench_ser
 
 from repro.core import graph as G  # noqa: E402
 from repro.core.passes.partition import PartitionConfig  # noqa: E402
-from repro.engine import Engine, InferenceRequest  # noqa: E402
+from repro.engine import (Engine, InferenceRequest,  # noqa: E402
+                          enable_compile_cache)
 from repro.runtime import Metrics, OverlayPool, ServeLoop  # noqa: E402
 from repro.runtime.metrics import percentile  # noqa: E402
 
@@ -160,6 +161,7 @@ def run(smoke: bool, n_requests: int, n_overlays: int, max_batch: int,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small graphs + short stream (CI gate)")

@@ -27,6 +27,8 @@ ALL = {
 
 
 def main() -> None:
+    from repro.engine import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="small graphs only (CI smoke of the harness)")

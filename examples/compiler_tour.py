@@ -9,7 +9,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.core import gnn_builders as B  # noqa: E402
 from repro.core import graph as G  # noqa: E402
-from repro.engine import Engine  # noqa: E402
+from repro.engine import Engine, enable_compile_cache  # noqa: E402
 from repro.core.isa import Opcode, disassemble  # noqa: E402
 from repro.core.passes import fusion, order_opt  # noqa: E402
 from repro.core.passes.partition import (PartitionConfig,  # noqa: E402
@@ -17,6 +17,7 @@ from repro.core.passes.partition import (PartitionConfig,  # noqa: E402
 
 
 def main() -> None:
+    enable_compile_cache()
     g = G.synthesize("CO").gcn_normalized()
     model = B.build("b7", g)   # SGC: the order optimizer's best case
 

@@ -14,9 +14,11 @@ import tempfile
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.engine import enable_compile_cache  # noqa: E402
 from repro.launch.train import main as train_main  # noqa: E402
 
 if __name__ == "__main__":
+    enable_compile_cache()
     arch = sys.argv[1] if len(sys.argv) > 1 else "xlstm-125m"
     ckpt = tempfile.mkdtemp(prefix="repro_ckpt_")
     sys.exit(train_main([

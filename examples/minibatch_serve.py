@@ -30,10 +30,12 @@ import numpy as np  # noqa: E402
 
 from repro.core import graph as G  # noqa: E402
 from repro.core.passes.partition import PartitionConfig  # noqa: E402
+from repro.engine import enable_compile_cache  # noqa: E402
 from repro.sampling import SamplingService, TargetRequest  # noqa: E402
 
 
 def main() -> None:
+    enable_compile_cache()
     # one deployed graph: RE-class power law, duplicate edges folded
     g = G.random_graph(466, 24000, seed=0, degree="powerlaw", alpha=1.1,
                        dedupe=True)

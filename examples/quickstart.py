@@ -22,11 +22,18 @@ from repro.core import gnn_builders as B  # noqa: E402
 from repro.core import graph as G  # noqa: E402
 from repro.core import reference as R  # noqa: E402
 from repro.core.perfmodel import predict_loh  # noqa: E402
-from repro.engine import Engine  # noqa: E402
+from repro.engine import Engine, enable_compile_cache  # noqa: E402
 from repro.obs import build_report  # noqa: E402
+
+# Agreement with the fp32 reference, relative to the output's scale.  It
+# is set by the engine's GEMM tiles, which run at the backend's default
+# matmul precision: exact fp32 on a CPU, bf16-rounded operands on a TPU.
+# The same bound as GCN's in chip_smoke.py (a v5e measured 2.7e-3 here).
+REL_TOL = 5e-3
 
 
 def main() -> None:
+    enable_compile_cache()
     # Cora statistics, synthesized (offline container).
     g = G.synthesize("CO").gcn_normalized()
     x = jnp.asarray(G.random_features(g, seed=1))
@@ -49,10 +56,10 @@ def main() -> None:
     print(f"predicted T_LoH on TPU v5e: {predict_loh(cr.program)*1e3:.3f} ms")
 
     y = engine.run(prog, x)                         # decodes the binary
-    y_ref = R.run_reference(model, g, x)
-    err = float(jnp.max(jnp.abs(y - y_ref)))
-    print(f"\noverlay output {y.shape}, max |err| vs reference: {err:.2e}")
-    assert err < 1e-4
+    err, rel = R.max_errors(y, R.run_reference_fp32(model, g, x))
+    print(f"\noverlay output {y.shape}, max |err| vs fp32 reference: "
+          f"{err:.2e} ({rel:.2e} of the output scale)")
+    assert rel < REL_TOL
 
     # Cost-model conformance: join the analytic per-layer predictions
     # with the wall time the executor just measured for this run.

@@ -19,12 +19,14 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.core import graph as G  # noqa: E402
 from repro.core.passes.partition import PartitionConfig  # noqa: E402
-from repro.engine import Engine, InferenceRequest  # noqa: E402
+from repro.engine import (Engine, InferenceRequest,  # noqa: E402
+                          enable_compile_cache)
 from repro.obs import enable_tracing  # noqa: E402
 from repro.runtime import OverlayPool, ServeLoop  # noqa: E402
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--out", default="trace.json")
     args = ap.parse_args()

@@ -8,7 +8,11 @@ Backends:
   * ``xla``    — jnp tile ops (vectorized gathers / dots), the production
                  path on CPU and the GSPMD path on TPU.
   * ``pallas`` — the hand-written Pallas kernels in ``repro.kernels``
-                 (VMEM BlockSpec tiling; interpret=True on CPU).
+                 (VMEM BlockSpec tiling, lowered through Mosaic;
+                 ``interpret=True`` runs them on the CPU).  MAX/MIN
+                 SpDMM and pair-sum SDDMM have no Pallas kernel and run
+                 the xla tile op instead; ``ACK.fallbacks`` counts those
+                 calls so a "pallas" run shows how much of it was not.
 
 Every tile function is jit-compiled once per *tile shape* and cached —
 never per model or per graph.  This is the overlay property: changing the
@@ -64,6 +68,8 @@ def _gemm_xla(h: jnp.ndarray, w: jnp.ndarray, acc: jnp.ndarray) -> jnp.ndarray:
 # --------------------------------------------------------------------------- #
 # SpDMM mode: blocked-ELL scatter-gather (Algorithms 2 & 4).
 #   out[r] (+)= reduce_k vals[r,k] * h_src[cols[r,k]]
+# SUM/MEAN never read the row flags (pad slots carry val 0), so their
+# callers may pass ``mask=None`` and get ``flag`` back untouched.
 # --------------------------------------------------------------------------- #
 @functools.partial(jax.jit, static_argnames=("op",))
 def _spdmm_xla(h_src, cols, vals, mask, acc, flag, op: str):
@@ -71,7 +77,7 @@ def _spdmm_xla(h_src, cols, vals, mask, acc, flag, op: str):
     if op in ("sum", "mean"):
         msg = gathered * vals[..., None]
         out = acc + jnp.sum(msg, axis=1)
-        return out, flag | mask.any(axis=1)
+        return out, flag if mask is None else flag | mask.any(axis=1)
     big = jnp.float32(3.4e38)
     msg = gathered * vals[..., None]
     if op == "max":
@@ -145,10 +151,11 @@ def _affine_xla(x, scale, shift):
 class ACK:
     """Mode-switched compute engine; see module docstring."""
 
-    def __init__(self, backend: str = "xla", interpret: bool = True) -> None:
+    def __init__(self, backend: str = "xla", interpret: bool = False) -> None:
         assert backend in ("xla", "pallas")
         self.backend = backend
         self.interpret = interpret
+        self.fallbacks = 0      # pallas-backend calls served by xla ops
         if backend == "pallas":
             from repro.kernels import ops as kops  # local import: optional
             self._kops = kops
@@ -172,16 +179,21 @@ class ACK:
     # -- SpDMM ---------------------------------------------------------- #
     def spdmm(self, h_src, cols, vals, mask, acc, flag, op: str = "sum"):
         _count(("spdmm", h_src.shape, cols.shape, op, self.backend))
-        if self.backend == "pallas" and op in ("sum", "mean"):
-            out = acc + self._kops.spdmm(cols, vals, h_src,
-                                         interpret=self.interpret)
-            return out, flag | mask.any(axis=1)
+        if self.backend == "pallas":
+            if op in ("sum", "mean"):
+                out = acc + self._kops.spdmm(cols, vals, h_src,
+                                             interpret=self.interpret)
+                return out, (flag if mask is None
+                             else flag | mask.any(axis=1))
+            self.fallbacks += 1
         return _spdmm_xla(h_src, cols, vals, mask, acc, flag, op)
 
     # -- SDDMM ---------------------------------------------------------- #
     def sddmm(self, h_dst, h_src, cols, mask, acc, pair_sum: bool = False):
         _count(("sddmm", h_dst.shape, cols.shape, pair_sum, self.backend))
         if pair_sum:
+            if self.backend == "pallas":
+                self.fallbacks += 1
             return _sddmm_pair_xla(h_dst, h_src, cols, mask, acc)
         if self.backend == "pallas":
             return acc + jnp.where(

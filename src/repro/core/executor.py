@@ -34,7 +34,7 @@ class OverlayExecutor:
     """Deprecated: use ``repro.engine.Engine`` instead."""
 
     def __init__(self, backend: str = "xla", overlap: bool = True,
-                 interpret: bool = True) -> None:
+                 interpret: bool = False) -> None:
         warnings.warn(
             "OverlayExecutor is deprecated; use repro.engine.Engine "
             "(binary-driven execution)", DeprecationWarning, stacklevel=2)

@@ -7,7 +7,7 @@ intermediates with no partitioning, fusion, or reordering.
 """
 from __future__ import annotations
 
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -135,3 +135,23 @@ def run_reference(
     # Output = last layer in topo order with no children.
     sinks = [i for i, l in model.layers.items() if not l.child_ids]
     return vals[sinks[-1]] if sinks else vals[out_id]
+
+
+def run_reference_fp32(
+    model: ModelIR, g: Graph, x: jnp.ndarray,
+    weights: Optional[Dict[str, np.ndarray]] = None,
+) -> jnp.ndarray:
+    """:func:`run_reference` with every matmul at full fp32 precision
+    (``"highest"``) — the oracle the engine is held to on any backend.
+    A TPU's default fp32 matmul rounds its operands to bf16, so an
+    oracle at default precision would carry errors of its own."""
+    with jax.default_matmul_precision("highest"):
+        return run_reference(model, g, x, weights)
+
+
+def max_errors(y, y_ref) -> Tuple[float, float]:
+    """(max |y - y_ref|, the same over max |y_ref|): the absolute and
+    the scale-relative agreement a tolerance is stated against."""
+    err = float(jnp.max(jnp.abs(jnp.asarray(y) - jnp.asarray(y_ref))))
+    scale = float(jnp.max(jnp.abs(jnp.asarray(y_ref))))
+    return err, err / max(scale, 1e-30)

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 
 
 def pipeline_apply(
@@ -78,7 +77,7 @@ def pipeline_apply(
             jnp.where(stage_id == n_stages - 1, out, jnp.zeros_like(out)),
             axis)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P(axis)),
         out_specs=P(),

@@ -24,6 +24,7 @@ The legacy ``repro.core.compiler.compile_model`` /
 deprecated shims over this package.
 """
 from .cache import LRUCache
+from .compile_cache import enable_compile_cache
 from .decoder import ExecutionPlan, LayerPlan, TilePlan, decode_binary
 from .engine import (Engine, EngineStats, InferenceRequest,
                      InferenceResponse, graph_signature, model_signature,
@@ -40,5 +41,5 @@ __all__ = [
     "derive_placement", "derive_residency", "ensure_placement",
     "ExecutionPlan", "LayerPlan", "TilePlan", "decode_binary",
     "build_manifest", "from_program", "graph_signature", "model_signature",
-    "stack_features", "stack_graph_data",
+    "stack_features", "stack_graph_data", "enable_compile_cache",
 ]

@@ -277,7 +277,7 @@ class Engine:
 
     def __init__(self, geometry: Optional[PartitionConfig] = None,
                  n_pes: int = 8, backend: str = "xla", *,
-                 overlap: bool = True, interpret: bool = True,
+                 overlap: bool = True, interpret: bool = False,
                  vmem_budget_bytes: int = 3 << 20,
                  cache_capacity: int = 32,
                  resident_budget_bytes: Optional[int] = None,

@@ -30,7 +30,7 @@ results bit-identical:
   * **mesh** — the placement-scheduled multi-device path: destination
     shards are LPT-assigned to the devices of a mesh (the manifest's
     ``placement`` section), each device executes its own greedy
-    max-overlap shard order under ``repro.compat.shard_map``, and halo
+    max-overlap shard order under ``jax.shard_map``, and halo
     sub-fibers (source blocks a device does not own) move through an
     ``all_gather`` collective before aggregation layers.  The
     compile-time halo sets price the exchange; per-device counters land
@@ -85,17 +85,62 @@ _KERNEL_MODES = {
 
 
 def _tile_arrays(pg, gtiles, j: int, k: int, s: int):
-    """(cols, vals, mask, epos) of tile (j, k, s) — from the runtime
-    ``graph_data`` when present, else from the program's baked tiles.
-    Shapes agree by the canonical-layout contract, so the same traced
-    computation serves both sources.  Baked arrays stay on the host
-    (numpy) — consumers device-convert implicitly on use, so unused
-    elements cost nothing on the eager path."""
+    """(cols, vals, mask, epos) of tile (j, k, s) — from ``gtiles`` (the
+    runtime ``graph_data`` or the program's :func:`device_tiles`) when
+    given, else the baked host (numpy) tiles, which consumers
+    device-convert on use.  Shapes agree by the canonical-layout
+    contract, so the same traced computation serves every source.  A
+    tile without ``mask`` derives it from ``epos`` (-1 on pad slots)."""
     if gtiles is None:
         t = pg.tiles[(j, k)][s]
         return t.cols, t.vals, t.edge_pos >= 0, t.edge_pos
     d = gtiles[f"{j}:{k}:{s}"]
-    return d["cols"], d["vals"], d["mask"], d["epos"]
+    epos = d.get("epos")
+    mask = d["mask"] if "mask" in d else (
+        None if epos is None else epos >= 0)
+    return d["cols"], d["vals"], mask, epos
+
+
+def reads_edges(plan) -> bool:
+    """Whether a decoded program reads per-edge ids or pad masks: edge
+    scoring, edge activations, or MAX/MIN aggregation (row flags).
+    SUM/MEAN aggregation over static weights needs neither — pad slots
+    carry weight 0."""
+    return any(
+        lp.layer_type == LayerType.VECTOR_INNER or lp.on_edges
+        or (lp.layer_type == LayerType.AGGREGATE
+            and AggOp(lp.mode) in (AggOp.MAX, AggOp.MIN))
+        for lp in plan.layers)
+
+
+_PLACED_FIELDS = {"cols": "cols", "vals": "vals", "epos": "edge_pos"}
+
+
+def device_tiles(pg, edges: bool = True) -> dict:
+    """The partitioned graph's ELL tiles and inverse in-degrees on the
+    default device, in the ``graph_data`` layout (``mask`` derived from
+    ``epos`` on use).  Each array is placed once per graph and kept (every
+    program copy and livegraph version sharing the graph shares it);
+    ``edges=False`` leaves the edge ids on the host for programs that
+    never read them (see :func:`reads_edges`).  The device-resident path
+    reads its tiles from here and the batched path passes them into its
+    jitted pass as arguments, so tiles are neither shipped per tile op
+    nor baked into an executable as constants."""
+    placed = pg.__dict__.setdefault("_device_tiles", {})
+    fields = ("cols", "vals", "epos") if edges else ("cols", "vals")
+    with jax.ensure_compile_time_eval():
+        for f in fields:
+            if f not in placed:
+                placed[f] = {
+                    f"{j}:{k}:{s}": jax.device_put(
+                        getattr(t, _PLACED_FIELDS[f]))
+                    for (j, k), ts in pg.tiles.items()
+                    for s, t in enumerate(ts)}
+        if "inv" not in placed:
+            placed["inv"] = jax.device_put(pg.inv_in_degree)
+    return {"tiles": {key: {f: placed[f][key] for f in fields}
+                      for key in placed["cols"]},
+            "inv_in_degree": placed["inv"]}
 
 
 def _row_tiles(pg, j: int) -> List[Tuple[int, int]]:
@@ -128,6 +173,9 @@ class ExecStats:
     shards_streamed: int = 0        # destination shards staged (host mode)
     h2d_bytes: int = 0              # bytes shipped host -> device
     peak_stage_bytes: int = 0       # double-buffered working set peak
+    # Pallas-backend ACK calls served by an xla tile op instead
+    # (MAX/MIN SpDMM, pair-sum SDDMM — no Pallas kernel for those).
+    pallas_fallbacks: int = 0
     # Multi-device placement telemetry (mesh mode).
     n_devices: int = 1              # mesh size of the last run
     halo_bytes: int = 0             # compile-time halo exchange volume
@@ -160,6 +208,7 @@ class ExecStats:
         self.runs += other.runs
         self.tiles_remapped += other.tiles_remapped
         self.tiles_skipped += other.tiles_skipped
+        self.pallas_fallbacks += other.pallas_fallbacks
         if other.tile_ops_by_mode is not None:
             for m, n in other.tile_ops_by_mode.items():
                 self.note_mode(m, n)
@@ -587,7 +636,8 @@ class _AggregateKernel(_ShardKernel):
                         dense = densify_tile(cols, v, n_src=self.n1)
                         self._dense[(j, k, s)] = dense
                     acc = self.ex.ack.gemm(dense, h_tile, acc)
-                flag = flag | mask.any(axis=1)
+                if mask is not None:
+                    flag = flag | mask.any(axis=1)
                 self.ex.stats.tiles_remapped += 1
                 self.ex.stats.note_mode("gemm")
             else:
@@ -753,7 +803,7 @@ class BinaryExecutor:
     """
 
     def __init__(self, backend: str = "xla", overlap: bool = True,
-                 interpret: bool = True,
+                 interpret: bool = False,
                  resident_budget_bytes: Optional[int] = None) -> None:
         self.ack = ACK(backend=backend, interpret=interpret)
         self.overlap = overlap
@@ -775,13 +825,21 @@ class BinaryExecutor:
     def _residency(self, prog: CompiledProgram) -> dict:
         return resolve_residency(prog)
 
-    def _note_skips(self, prog: CompiledProgram) -> None:
-        """Credit the run's skip-empty elisions from the remap record —
-        the decoder drops NOPed steps, so the executor can't observe
-        them; the record is how many compute steps one pass elides."""
+    def _begin_run(self, prog: CompiledProgram, **stats) -> None:
+        """Fresh per-run stats; credits the run's skip-empty elisions
+        from the remap record (the decoder drops NOPed steps, so the
+        executor can't observe them)."""
+        self.stats = ExecStats(runs=1, **stats)
         rec = prog.manifest.get("remap")
         if rec:
             self.stats.tiles_skipped = int(rec.get("skipped_tile_ops", 0))
+        self._fallbacks0 = self.ack.fallbacks
+        self._begin_profile()
+
+    def _end_run(self, prog: CompiledProgram) -> None:
+        self.stats.pallas_fallbacks = self.ack.fallbacks - self._fallbacks0
+        self._flush_profile(prog)
+        self.total.add(self.stats)
 
     def _make_kernel(self, lp: LayerPlan, meta: dict, pg,
                      weights) -> _ShardKernel:
@@ -1010,10 +1068,8 @@ class BinaryExecutor:
                     "(bucketed subgraphs are small by construction)")
             return self._run_host(prog, [x], weights)[0]
         self._gate_device_budget(prog, int(x.shape[1]))
-        self.stats = ExecStats(runs=1)
-        self._note_skips(prog)
+        self._begin_run(prog)
         tracer = get_tracer()
-        self._begin_profile()
         with tracer.span("decode", cat="exec", track="exec:device",
                          args={"cached": prog._plan is not None}):
             plan = prog.plan()
@@ -1021,7 +1077,9 @@ class BinaryExecutor:
         pg = prog.pgraph
         res = self._residency(prog)
         last_use = {int(k): v for k, v in res["last_use"].items()}
-        gtiles = graph_data["tiles"] if graph_data is not None else None
+        if graph_data is None:
+            graph_data = device_tiles(pg, edges=reads_edges(plan))
+        gtiles = graph_data["tiles"]
         weights = weights if weights is not None else prog.weights
         lmeta = man["layers"]
         n1, n2, nb = pg.config.n1, pg.config.n2, pg.n_blocks
@@ -1041,9 +1099,7 @@ class BinaryExecutor:
                                   ((x.shape[1] + n2 - 1) // n2) * n2))
         vals: Dict[int, jnp.ndarray] = {}       # layer -> padded output
         edge_vals: Dict[int, jnp.ndarray] = {}  # layer -> (E,) edge scores
-        inv_deg = jnp.asarray(graph_data["inv_in_degree"]
-                              if graph_data is not None
-                              else pg.inv_in_degree)
+        inv_deg = jnp.asarray(graph_data["inv_in_degree"])
 
         sink = man["sink"]
         for t, lp in enumerate(plan.layers):
@@ -1112,8 +1168,7 @@ class BinaryExecutor:
             # ran, so peak memory follows the live-set, not model depth.
             self._free_dead(t, sink, last_use, vals, edge_vals)
 
-        self._flush_profile(prog)
-        self.total.add(self.stats)
+        self._end_run(prog)
         return vals[sink][:nv, :man["sink_f_out"]]
 
     # ------------------------------------------------------------------ #
@@ -1135,7 +1190,10 @@ class BinaryExecutor:
         traffic — repeated batches of the same deployed (model, graph)
         pair — replays a compiled whole-program executable with zero
         Python-side instruction dispatch, which is what lets the
-        serving runtime saturate the substrate.  (A ``weights``
+        serving runtime saturate the substrate.  Graph tiles enter the
+        pass as arguments — the request's ``graph_data`` (batched) or
+        the program's :func:`device_tiles` (shared by every lane) — so
+        the executable holds no graph constants.  (A ``weights``
         override bypasses the memo: the executable closes over the
         program's own weights.)
         """
@@ -1175,33 +1233,31 @@ class BinaryExecutor:
         # and memoized replays never re-enter run() at all.
         self._gate_device_budget(prog, int(xs.shape[2]),
                                  batch=int(xs.shape[0]))
+        batched = graph_data is not None
+        gd = graph_data if batched else device_tiles(
+            prog.pgraph, edges=reads_edges(prog.plan()))
+        axes = (0, 0 if batched else None)
         if weights is not None:
-            if graph_data is not None:
-                return jax.vmap(lambda x, gd: self.run(
-                    prog, x, weights=weights, graph_data=gd)
-                )(xs, graph_data)
-            return jax.vmap(lambda x: self.run(prog, x,
-                                               weights=weights))(xs)
+            return jax.vmap(lambda x, g: self.run(
+                prog, x, weights=weights, graph_data=g), in_axes=axes
+            )(xs, gd)
         # graph_data shapes are fixed by the program's canonical layout,
         # so (batch shape, presence flag) fully keys the executable.
-        key = (tuple(xs.shape), str(xs.dtype), graph_data is not None,
+        key = (tuple(xs.shape), str(xs.dtype), batched,
                self.ack.backend, self.ack.interpret, self.overlap)
         cache = prog.__dict__.setdefault("_batch_exec", {})
         entry = cache.get(key)
         if entry is None:
-            if graph_data is not None:
-                fn = jax.jit(jax.vmap(
-                    lambda x, gd: self.run(prog, x, graph_data=gd)))
-                y = fn(xs, graph_data)  # traces now; run() sets stats
-            else:
-                fn = jax.jit(jax.vmap(lambda x: self.run(prog, x)))
-                y = fn(xs)
+            fn = jax.jit(jax.vmap(
+                lambda x, g: self.run(prog, x, graph_data=g),
+                in_axes=axes))
+            y = fn(xs, gd)          # traces now; run() sets stats
             cache[key] = (fn, dataclasses.replace(self.stats))
             return y
         fn, stats = entry
         self.stats = dataclasses.replace(stats)
         self.total.add(self.stats)
-        return fn(xs, graph_data) if graph_data is not None else fn(xs)
+        return fn(xs, gd)
 
     # ------------------------------------------------------------------ #
     # Partition-centric out-of-core execution (paper §6.5, Alg. 6-8).
@@ -1286,10 +1342,8 @@ class BinaryExecutor:
         shard's tile working set (``stage_shared``) ships host->device
         once for the whole batch, each lane adds only its source
         sub-fibers (``stage_lane``) — host-path batching."""
-        self.stats = ExecStats(runs=1)
-        self._note_skips(prog)
+        self._begin_run(prog)
         tracer = get_tracer()
-        self._begin_profile()
         with tracer.span("decode", cat="exec", track="exec:host",
                          args={"cached": prog._plan is not None,
                                "lanes": len(xs)}):
@@ -1417,8 +1471,7 @@ class BinaryExecutor:
 
         ys = [jnp.asarray(vals[ln][sink][:nv, : man["sink_f_out"]])
               for ln in range(L)]
-        self._flush_profile(prog)
-        self.total.add(self.stats)
+        self._end_run(prog)
         return ys
 
     # ------------------------------------------------------------------ #
@@ -1503,7 +1556,7 @@ class BinaryExecutor:
     # committed [B*n1, f] slab per device (B = row blocks per device).
     # Each layer: (1) if the layer's halo sets are non-empty, the parent
     # slabs are exchanged with an ``all_gather`` collective under
-    # ``repro.compat.shard_map`` — the halo-exchange step, priced at
+    # ``jax.shard_map`` — the halo-exchange step, priced at
     # compile time by the placement's halo sets; (2) every device then
     # executes ITS OWN greedy max-overlap shard order, dispatching the
     # same jitted ACK tile kernels as the single-device path on its
@@ -1524,8 +1577,6 @@ class BinaryExecutor:
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
 
-        from repro.compat import shard_map as _shard_map
-
         D = len(slabs)
         rows = int(slabs[0].shape[0])
         with get_tracer().span(
@@ -1535,7 +1586,7 @@ class BinaryExecutor:
             global_x = jax.make_array_from_single_device_arrays(
                 (D * rows, width), NamedSharding(mesh, P(axis)),
                 list(slabs))
-            fn = _shard_map(lambda v: jax.lax.all_gather(v, axis),
+            fn = jax.shard_map(lambda v: jax.lax.all_gather(v, axis),
                             mesh=mesh, in_specs=P(axis), out_specs=P(),
                             check_vma=False)
             gathered = fn(global_x)      # [D, rows, f], replicated
@@ -1548,7 +1599,6 @@ class BinaryExecutor:
         D = int(mesh.size)
         devs = list(np.asarray(mesh.devices).reshape(-1))
         tracer = get_tracer()
-        self._begin_profile()
         pl = ensure_placement(prog, D)
         with tracer.span("decode", cat="exec", track="exec:dev0",
                          args={"cached": prog._plan is not None,
@@ -1587,8 +1637,7 @@ class BinaryExecutor:
                 slab[s * n1:s * n1 + blk.shape[0], : blk.shape[1]] = blk
             x_slabs.append(jax.device_put(slab, devs[d]))
 
-        self.stats = ExecStats(runs=1, n_devices=D)
-        self._note_skips(prog)
+        self._begin_run(prog, n_devices=D)
         per_dev = [{"device": d, "tile_ops": 0, "shards": 0,
                     "halo_bytes": 0, "blocks": len(owned[d])}
                    for d in range(D)]
@@ -1732,8 +1781,7 @@ class BinaryExecutor:
         self.stats.per_device = per_dev
         self.stats.halo_bytes = sum(d["halo_bytes"] for d in per_dev)
         self.stats.peak_device_bytes = peak_dev
-        self._flush_profile(prog)
-        self.total.add(self.stats)
+        self._end_run(prog)
         out = np.zeros((nb * n1, int(vals[sink][0].shape[1])),
                        np.float32)
         for j in range(nb):

@@ -2,9 +2,10 @@
 
 These pad arbitrary shapes up to block multiples, invoke the kernel, and
 slice back — so the ACK can call them with the compiler's native tile
-shapes.  ``interpret=True`` executes the kernel body in Python on CPU
-(correctness path in this container); on a real TPU, interpret=False
-lowers through Mosaic.
+shapes.  ELL widths and source-row counts are padded to whole 128-lane
+vregs (pad slots are zero-valued, pad rows never indexed).  Kernels lower
+through Mosaic by default; ``interpret=True`` runs the kernel body on the
+CPU instead (how the tests run them without a TPU).
 """
 from __future__ import annotations
 
@@ -31,7 +32,7 @@ def _pad_to(x: jnp.ndarray, mults) -> jnp.ndarray:
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "bm", "bk", "bn"))
-def gemm(x, w, *, interpret: bool = True, bm: int = 128, bk: int = 128,
+def gemm(x, w, *, interpret: bool = False, bm: int = 128, bk: int = 128,
          bn: int = 128):
     m, n = x.shape[0], w.shape[1]
     bm_, bk_, bn_ = (min(bm, _ceil(x.shape[0])), min(bk, _ceil(x.shape[1])),
@@ -49,25 +50,27 @@ def _ceil(d: int, base: int = 8) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "bm", "bf"))
-def spdmm(cols, vals, h, *, interpret: bool = True, bm: int = 128,
+def spdmm(cols, vals, h, *, interpret: bool = False, bm: int = 128,
           bf: int = 128):
     n1, f = cols.shape[0], h.shape[1]
     bm_, bf_ = min(bm, _ceil(n1)), min(bf, _ceil(f))
-    colsp = _pad_to(cols, (bm_, 1))
-    valsp = _pad_to(vals, (bm_, 1))
-    hp = _pad_to(h, (1, bf_))
-    out = _spdmm.spdmm(colsp, valsp, hp, bm=bm_, bf=bf_, interpret=interpret)
+    colsp = _pad_to(cols, (bm_, _LANE))
+    valsp = _pad_to(vals, (bm_, _LANE))
+    hp = _pad_to(h, (_LANE, bf_))
+    out = _spdmm.spdmm(colsp, valsp, hp, bm=bm_, bf=bf_,
+                       width=cols.shape[1], interpret=interpret)
     return out[:n1, :f]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "bm", "bf"))
-def sddmm(h_dst, h_src, cols, *, interpret: bool = True, bm: int = 128,
+def sddmm(h_dst, h_src, cols, *, interpret: bool = False, bm: int = 128,
           bf: int = 128):
     n1, w = cols.shape
     f = h_dst.shape[1]
     bm_, bf_ = min(bm, _ceil(n1)), min(bf, _ceil(f))
     hd = _pad_to(h_dst, (bm_, bf_))
-    hs = _pad_to(h_src, (1, bf_))
-    colsp = _pad_to(cols, (bm_, 1))
-    out = _sddmm.sddmm(hd, hs, colsp, bm=bm_, bf=bf_, interpret=interpret)
+    hs = _pad_to(h_src, (_LANE, bf_))
+    colsp = _pad_to(cols, (bm_, _LANE))
+    out = _sddmm.sddmm(hd, hs, colsp, bm=bm_, bf=bf_, width=w,
+                       interpret=interpret)
     return out[:n1, :w]

@@ -23,7 +23,6 @@ import jax  # noqa: E402
 from jax.sharding import NamedSharding  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.configs import ARCHS, get_config  # noqa: E402
 from repro.distributed import sharding as SH  # noqa: E402
 from repro.distributed.zero import opt_state_specs  # noqa: E402
@@ -115,7 +114,7 @@ def run_cell(arch: str, shape: str, mesh_kind: str,
     }
 
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if cell.kind == "train":
             fn = make_train_step(model, cfg)
             ospec = jax.eval_shape(adamw_init, param_s)

@@ -7,7 +7,16 @@ data parallelism across pods (DCN-ish), model stays within a pod (ICI).
 """
 from __future__ import annotations
 
-from repro.compat import make_mesh
+import jax
+
+
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD propagation),
+    the sharding mode the models and the dry-run are written for."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axis_names)),
+        devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -28,7 +37,6 @@ def make_device_mesh(n_devices=None):
     mesh axis.  ``n_devices=None`` takes every local device; an int
     takes the first N (``XLA_FLAGS=--xla_force_host_platform_device_count=4``
     forces virtual host devices for tests/CI)."""
-    import jax
     devs = jax.devices()
     n = len(devs) if n_devices is None else int(n_devices)
     if n < 1 or n > len(devs):

@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import get_abstract_mesh
 
 from .config import ModelConfig
 from .layers import Params, dense_init, rms_norm, rope
@@ -28,7 +27,7 @@ def _constrain(x: jnp.ndarray, *spec) -> jnp.ndarray:
     pin the MLA einsum chain to (batch->data, heads->model) — without it
     GSPMD picks contraction splits that all-reduce score-sized tensors
     inside the chunk loop (EXPERIMENTS.md §Perf, deepseek train_4k)."""
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or not mesh.axis_names:
         return x
     fixed = []

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 
 from .layers import Params, dense_init
 
@@ -161,7 +160,7 @@ def moe_local(p: Params, x: jnp.ndarray, top_k: int, cap_factor: float,
         y = jax.lax.psum(y, expert_axis)
         return y.reshape(bl, tl, d).astype(xl.dtype)
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_x, spec_f, spec_f, P(None, expert_axis, None),
                   P(None, expert_axis, None), P(None, expert_axis, None)),
@@ -234,7 +233,7 @@ def moe_a2a(p: Params, x: jnp.ndarray, top_k: int, cap_factor: float,
         return y
 
     spec_w = P(batch_axes, seq_axis if use_seq else None, None)
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_x, spec_w, spec_w, P(None, expert_axis, None),
                   P(None, expert_axis, None), P(None, expert_axis, None)),

@@ -58,7 +58,7 @@ def test_elastic_restore_resharded(tmp_path):
     code = f"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.checkpoint import save, restore
 t = {{"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}}
 save({str(tmp_path)!r}, 3, t)

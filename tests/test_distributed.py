@@ -93,13 +93,13 @@ def test_moe_a2a_matches_dense():
     code = """
     import jax, jax.numpy as jnp, numpy as np
     from repro.models import moe as MOE
-    from repro.compat import make_mesh, set_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((2, 4), ("data", "model"))
     d, f, e, topk = 16, 32, 8, 2
     p = MOE.moe_init(jax.random.PRNGKey(0), d, f, e, jnp.float32, n_shared=1)
     x = jnp.asarray(np.random.default_rng(0).normal(0, 1, (4, 8, d)).astype(np.float32))
     y_dense, aux_d = MOE.moe_dense(p, x, topk)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         y_a2a, aux_a = MOE.moe_a2a(p, x, topk, cap_factor=4.0, mesh=mesh)
     err = float(jnp.max(jnp.abs(y_dense - y_a2a)))
     print("ERR", err, float(aux_d), float(aux_a))
@@ -116,7 +116,7 @@ def test_zero_sharding_specs():
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
     from repro.distributed.zero import opt_state_specs, zero_param_spec
-    from repro.compat import make_mesh, set_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((2, 4), ("data", "model"))
     # plain leaf: first divisible dim gets 'data'
     s = zero_param_spec(P(None, "model"), (8, 16), mesh)
@@ -147,9 +147,9 @@ def test_sharded_train_step_matches_single_device():
     batch = {"tokens": jnp.asarray(rng.integers(0, cfg.vocab, (8, 32)).astype(np.int32)),
              "labels": jnp.asarray(rng.integers(0, cfg.vocab, (8, 32)).astype(np.int32))}
     p1, o1, m1 = jax.jit(ts)(params, opt, batch)
-    from repro.compat import make_mesh, set_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((4, 2), ("data", "model"))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         psh = SH.param_shardings(mesh, params)
         bsh = {k: NamedSharding(mesh, P(("data",), None)) for k in batch}
         f = jax.jit(ts, in_shardings=(psh, None, bsh))
@@ -171,7 +171,7 @@ def test_pipeline_parallel_equivalence():
     code = """
     import jax, jax.numpy as jnp, numpy as np
     from repro.distributed.pipeline import pipeline_apply
-    from repro.compat import make_mesh, set_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((4,), ("stage",))
     rng = np.random.default_rng(0)
     n_stages, n_micro, mb, d = 4, 8, 2, 16
@@ -179,7 +179,7 @@ def test_pipeline_parallel_equivalence():
     x = jnp.asarray(rng.normal(0, 1, (n_micro, mb, d)).astype(np.float32))
     def stage_fn(w, h):
         return jnp.tanh(h @ w)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         y_pipe = pipeline_apply(stage_fn, Ws, x, mesh, axis="stage")
     y_seq = x
     for s in range(n_stages):
@@ -198,7 +198,7 @@ def test_moe_local_matches_dense_decode():
     code = """
     import jax, jax.numpy as jnp, numpy as np
     from repro.models import moe as MOE
-    from repro.compat import make_mesh, set_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((2, 4), ("data", "model"))
     d, f, e, topk = 16, 32, 8, 2
     p = MOE.moe_init(jax.random.PRNGKey(0), d, f, e, jnp.float32, n_shared=1)
@@ -206,7 +206,7 @@ def test_moe_local_matches_dense_decode():
         x = jnp.asarray(np.random.default_rng(b).normal(0, 1, (b, t, d))
                         .astype(np.float32))
         y_dense, _ = MOE.moe_dense(p, x, topk)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             y_loc, _ = MOE.moe_local(p, x, topk, cap_factor=4.0, mesh=mesh)
         err = float(jnp.max(jnp.abs(y_dense - y_loc)))
         assert err < 2e-4, (b, t, err)

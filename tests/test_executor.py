@@ -65,9 +65,12 @@ def test_overlap_off_matches():
 
 
 def test_pallas_backend_matches():
-    eng = _engine(backend="pallas")
+    eng = _engine(backend="pallas", interpret=True)
     _check("b1", _g(nv=64, ne=200, f=8), engine=eng)
+    assert eng.exec_stats.pallas_fallbacks == 0
     _check("b6", _g(nv=64, ne=200, f=8), engine=eng)
+    # GAT's pair-sum scores have no Pallas kernel: counted, not hidden
+    assert eng.exec_stats.pallas_fallbacks > 0
 
 
 def test_max_min_aggregation():

@@ -93,7 +93,7 @@ def test_collectives_counted():
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.launch.hlo_analysis import analyze
         mesh = make_mesh((8,), ("d",))
         def f(x):
