@@ -221,9 +221,12 @@ def run_model(name: str, eng, g, x, reps: int, check_bits: bool,
     # Traced conformance pass (kernels now warm): per-layer measured
     # wall time joined against the analytic cost model, staging
     # bandwidth fitted from the stage spans, critical path from the
-    # span DAG.  This is the run the `model_error` gate prices.
+    # span DAG.  This is the run the `model_error` gate prices; its
+    # per-tile profile feeds the report's density join.
+    ex.profile_tiles = True
     with tracing() as tr:
         y_conf = np.asarray(eng.run(prog, x, residency="host"))
+    ex.profile_tiles = False
     assert np.array_equal(y, y_conf)
     rep = build_report(prog, eng.exec_stats, residency="host",
                        events=tr.events())

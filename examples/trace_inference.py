@@ -7,8 +7,10 @@ Open the written file at https://ui.perfetto.dev — the `compile` track
 shows the §6 pass pipeline, `h2d` the double-buffered shard staging,
 `exec:host` the per-shard compute (watch the stage spans of shard j+1
 overlap the compute span of shard j — the paper's T_LoC/T_LoH overlap,
-made visible), and `queue`/`overlay*` the request lifecycle through the
-serving loop.
+made visible), and `serve` each request's life through the serving loop
+(batching, hand-off to its overlay, response), with each batch's
+`exec.batch_stage`, `exec.batch_pass` and `exec.batch_unstack` on its
+overlay's thread.
 """
 import argparse
 import json
@@ -46,9 +48,10 @@ def main() -> None:
           f"{engine.exec_stats.shards_streamed} shards streamed, "
           f"{engine.exec_stats.h2d_bytes} h2d bytes")
 
-    # A little serving traffic: admission -> queue wait -> batch ->
-    # execute spans through the ServeLoop (cache-hit instants on the
-    # second wave).
+    # A little serving traffic: serve.batching -> serve.handoff ->
+    # exec.batch_stage / exec.batch_pass / exec.batch_unstack ->
+    # serve.respond spans through the ServeLoop (cache-hit instants on
+    # the second wave).
     pool = OverlayPool(n_overlays=2, geometry=PartitionConfig(n1=32, n2=8))
     loop = ServeLoop(pool, max_batch=4)
     reqs = [InferenceRequest(model="b1", graph=g, features=x,

@@ -57,10 +57,27 @@ def counter_snapshot() -> Dict[Tuple, int]:
         return dict(compile_counter)
 
 
+def _mode(name: str):
+    """Put a tile function's body under ``jax.named_scope("ack.<name>")``
+    inside its jit: the scope costs nothing per call, and it names the
+    mode's operations in every executable that inlines the function
+    (the jitted batched pass), where a device trace finds them by
+    scope.  ``functools.wraps`` keeps the function's name, so its own
+    executable keeps its name too (``jit__spdmm_xla``, ...)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(f"ack.{name}"):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
+
+
 # --------------------------------------------------------------------------- #
 # GEMM mode: output-stationary blocked matmul (Algorithm 1).
 # --------------------------------------------------------------------------- #
 @jax.jit
+@_mode("gemm")
 def _gemm_xla(h: jnp.ndarray, w: jnp.ndarray, acc: jnp.ndarray) -> jnp.ndarray:
     return acc + jnp.dot(h, w, preferred_element_type=jnp.float32)
 
@@ -72,6 +89,7 @@ def _gemm_xla(h: jnp.ndarray, w: jnp.ndarray, acc: jnp.ndarray) -> jnp.ndarray:
 # callers may pass ``mask=None`` and get ``flag`` back untouched.
 # --------------------------------------------------------------------------- #
 @functools.partial(jax.jit, static_argnames=("op",))
+@_mode("spdmm")
 def _spdmm_xla(h_src, cols, vals, mask, acc, flag, op: str):
     gathered = h_src[cols]                       # [n1, w, n2]
     if op in ("sum", "mean"):
@@ -98,6 +116,7 @@ def _spdmm_xla(h_src, cols, vals, mask, acc, flag, op: str):
 # sum, matching SpDMM's per-edge accumulation.
 # --------------------------------------------------------------------------- #
 @functools.partial(jax.jit, static_argnames=("n_src",))
+@_mode("densify")
 def densify_tile(cols, vals, n_src: int) -> jnp.ndarray:
     """Scatter an ELL slice into its (n1, n_src) dense adjacency block.
     Executors cache the result per (j, k, s) so one densification feeds
@@ -108,6 +127,7 @@ def densify_tile(cols, vals, n_src: int) -> jnp.ndarray:
 
 
 @jax.jit
+@_mode("gemm_agg")
 def _gemm_agg_xla(cols, vals, h_src, acc):
     rows = jnp.arange(cols.shape[0])[:, None]
     dense = jnp.zeros((cols.shape[0], h_src.shape[0]),
@@ -120,6 +140,7 @@ def _gemm_agg_xla(cols, vals, h_src, acc):
 #   score[r, k] = <h_dst[r], h_src[cols[r, k]]>
 # --------------------------------------------------------------------------- #
 @jax.jit
+@_mode("sddmm")
 def _sddmm_xla(h_dst, h_src, cols, mask, acc):
     gathered = h_src[cols]                       # [n1, w, n2]
     part = jnp.einsum("rwf,rf->rw", gathered, h_dst)
@@ -127,6 +148,7 @@ def _sddmm_xla(h_dst, h_src, cols, mask, acc):
 
 
 @jax.jit
+@_mode("sddmm")
 def _sddmm_pair_xla(h_dst, h_src, cols, mask, acc):
     """GAT pair scores: score[r,k] = h_src[cols[r,k], 0] + h_dst[r, 1]."""
     part = h_src[cols][:, :, 0] + h_dst[:, 1][:, None]
@@ -134,16 +156,19 @@ def _sddmm_pair_xla(h_dst, h_src, cols, mask, acc):
 
 
 @jax.jit
+@_mode("vadd")
 def _vadd_xla(a, b, alpha, beta):
     return alpha * a + beta * b
 
 
 @functools.partial(jax.jit, static_argnames=("act",))
+@_mode("act")
 def _act_xla(x, act: int):
     return apply_activation(x, Activation(act))
 
 
 @jax.jit
+@_mode("affine")
 def _affine_xla(x, scale, shift):
     return x * scale + shift
 

@@ -614,17 +614,24 @@ class Engine:
         """
         if not reqs:
             return []
+        # Host spans of one batch, on the calling thread:
+        # ``exec.batch_stage`` from here to the pass (live-version pins,
+        # key checks, cache lookup, stacking and padding), then
+        # ``exec.batch_pass`` (the t_loh interval) and
+        # ``exec.batch_unstack`` (per-request output slices and the
+        # responses).
+        stage = get_tracer().span("exec.batch_stage", cat="exec")
         admitted = [self._admit_live(r) for r in reqs]
         reqs = [r for r, _ in admitted]
         pins = [p for _, p in admitted if p is not None]
         try:
-            return self._submit_batch_resolved(reqs)
+            return self._submit_batch_resolved(reqs, stage)
         finally:
             for server, vid in pins:
                 server.release(vid)
 
-    def _submit_batch_resolved(self, reqs: Sequence[InferenceRequest]
-                               ) -> List[InferenceResponse]:
+    def _submit_batch_resolved(self, reqs: Sequence[InferenceRequest],
+                               stage) -> List[InferenceResponse]:
         key = self.cache_key(reqs[0].model, reqs[0].graph,
                              seed=reqs[0].seed)
         for r in reqs[1:]:
@@ -673,10 +680,15 @@ class Engine:
             xs = jnp.pad(xs, ((0, bucket - n), (0, 0), (0, 0)))
         gd = stack_graph_data([r.graph_data for r in reqs], bucket) \
             if with_gd else None
-        t0 = time.perf_counter()
+        stage.done()
+        t0 = time.perf_counter_ns()
         ys = self.run_batch(prog, xs, graph_data=gd)[:n]
         jax.block_until_ready(ys)
-        t_loh = time.perf_counter() - t0
+        t1 = time.perf_counter_ns()
+        tracer = get_tracer()
+        tracer.complete("exec.batch_pass", t0, t1, cat="exec",
+                        args={"requests": n, "lanes": bucket})
+        t_loh = (t1 - t0) * 1e-9
         t_loc = 0.0 if hit else prog.t_loc
 
         base = self.stats.requests
@@ -684,11 +696,12 @@ class Engine:
         self.stats.cache_hits += n * int(hit)
         self.stats.cache_misses += n * int(not hit)
         self.stats.total_t_loh += t_loh
-        return [InferenceResponse(
-            request_id=r.request_id or f"req{base + i}", output=ys[i],
-            t_loc=t_loc, t_loh=t_loh, cache_hit=hit, cache_key=key,
-            model_name=prog.model_name, graph_name=r.graph.name,
-            batch_size=n) for i, r in enumerate(reqs)]
+        with tracer.span("exec.batch_unstack", cat="exec"):
+            return [InferenceResponse(
+                request_id=r.request_id or f"req{base + i}", output=ys[i],
+                t_loc=t_loc, t_loh=t_loh, cache_hit=hit, cache_key=key,
+                model_name=prog.model_name, graph_name=r.graph.name,
+                batch_size=n) for i, r in enumerate(reqs)]
 
     def serve(self, requests: Iterable[InferenceRequest]
               ) -> List[InferenceResponse]:

@@ -66,7 +66,7 @@ from repro.core.ack import ACK, densify_tile
 from repro.core.ir import Activation, AggOp, LayerType
 from repro.core.isa import Opcode
 from repro.core.reference import apply_activation
-from repro.obs.tracer import get_tracer
+from repro.obs.tracer import NullTracer, get_tracer
 
 from .decoder import LayerPlan, TilePlan
 from .program import CompiledProgram
@@ -813,9 +813,9 @@ class BinaryExecutor:
         # materialized or released (tests count liveness through this).
         self.liveness_hook = None
         # Per-tile execution profiling (density + kernel mode, the
-        # Dynasparse remapper's input): collected whenever tracing is
-        # enabled OR this flag is set, folded into the program manifest
-        # as ``exec_profile`` at the end of each run.
+        # Dynasparse remapper's input): collected while this flag is
+        # set, folded into the program manifest as ``exec_profile`` at
+        # the end of each run.  Tracing does not turn it on.
         self.profile_tiles = False
         self._tile_records: Optional[dict] = None
         self.stats = ExecStats()        # per-run (last run)
@@ -943,7 +943,7 @@ class BinaryExecutor:
     # kernel mode ran each graph tile, how often, against what density.
     # ------------------------------------------------------------------ #
     def _begin_profile(self) -> None:
-        if get_tracer().enabled or self.profile_tiles:
+        if self.profile_tiles:
             self._tile_records = {"modes": {}, "tiles": {}}
         else:
             self._tile_records = None
@@ -1069,7 +1069,10 @@ class BinaryExecutor:
             return self._run_host(prog, [x], weights)[0]
         self._gate_device_budget(prog, int(x.shape[1]))
         self._begin_run(prog)
-        tracer = get_tracer()
+        # On traced values (run_batch's jit and vmap) this host code
+        # builds the pass rather than runs it: it records no spans.
+        tracer = (NullTracer() if isinstance(x, jax.core.Tracer)
+                  else get_tracer())
         with tracer.span("decode", cat="exec", track="exec:device",
                          args={"cached": prog._plan is not None}):
             plan = prog.plan()
@@ -1248,9 +1251,12 @@ class BinaryExecutor:
         cache = prog.__dict__.setdefault("_batch_exec", {})
         entry = cache.get(key)
         if entry is None:
-            fn = jax.jit(jax.vmap(
-                lambda x, g: self.run(prog, x, graph_data=g),
-                in_axes=axes))
+            # Named, so the device trace shows one stable executable
+            # name for the pass: ``jit_batched_pass``.
+            def batched_pass(x, g):
+                return self.run(prog, x, graph_data=g)
+
+            fn = jax.jit(jax.vmap(batched_pass, in_axes=axes))
             y = fn(xs, gd)          # traces now; run() sets stats
             cache[key] = (fn, dataclasses.replace(self.stats))
             return y

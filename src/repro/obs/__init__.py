@@ -6,6 +6,9 @@ Four parts:
   counters, instant events) exported as Chrome/Perfetto trace-event
   JSON, threaded through the compiler passes, every executor residency
   path, and the serving runtime.  Zero overhead when disabled.
+  ``Tracer.anchor()``, called inside a ``jax.profiler`` session at the
+  start and end of a window, puts the tracer's clock on the profile's,
+  so its spans can be laid on the device's timeline.
 * :mod:`repro.obs.attrib` — trace analysis: span-DAG reconstruction,
   critical path, per-span slack/stall, and the measured
   per-(layer, tile-block, kernel-mode) attribution table.

@@ -36,6 +36,27 @@ Chrome trace-event specifics: spans are emitted as ``"X"`` (complete)
 events with microsecond ``ts``/``dur`` relative to tracer start;
 tracks are (pid=1, tid) pairs named via ``thread_name`` metadata
 events.  Fractional microseconds are allowed by both viewers.
+
+On a device trace's clock: the tracer keeps its own
+``perf_counter_ns`` clock, and :meth:`Tracer.anchor` stamps it onto a
+running ``jax.profiler`` trace.  Call it inside the profiler session at
+the start and at the end of the window::
+
+    tracer = enable_tracing()
+    jax.profiler.start_trace(log_dir)
+    tracer.anchor()
+    ...   # the window
+    tracer.anchor()
+    jax.profiler.stop_trace()
+
+Each call writes a zero-length ``obs.clock_anchor`` annotation into the
+profile, whose ``perf_ns`` stat is the tracer-clock time it was opened
+at, and an instant of the same name, with the same ``perf_ns``, into the
+tracer's events.  A reader pairs the two: the anchors' trace times
+against their ``perf_ns`` give the offset from this clock to the
+trace's and the drift between the two, and every span, including one
+closed in another thread by :meth:`Tracer.complete`, maps onto the
+device's timeline through them.
 """
 from __future__ import annotations
 
@@ -46,8 +67,11 @@ from typing import Any, Dict, List, Optional
 
 __all__ = [
     "Tracer", "NullTracer", "get_tracer", "set_tracer",
-    "enable_tracing", "disable_tracing", "tracing",
+    "enable_tracing", "disable_tracing", "tracing", "CLOCK_ANCHOR",
 ]
+
+# Name of the annotation and instant that :meth:`Tracer.anchor` writes.
+CLOCK_ANCHOR = "obs.clock_anchor"
 
 
 class _Span:
@@ -131,6 +155,9 @@ class NullTracer:
         pass
 
     def now_ns(self) -> int:
+        return 0
+
+    def anchor(self) -> int:
         return 0
 
     def to_dict(self) -> dict:
@@ -220,13 +247,31 @@ class Tracer:
     def instant(self, name: str, cat: str = "",
                 args: Optional[dict] = None,
                 track: Optional[str] = None) -> None:
-        t = time.perf_counter_ns()
+        self._emit_instant(name, cat, time.perf_counter_ns(),
+                           dict(args) if args else {}, track)
+
+    def _emit_instant(self, name: str, cat: str, t_ns: int, args: dict,
+                      track: Optional[str]) -> None:
         with self._lock:
             self._events.append({
                 "ph": "i", "s": "t", "name": name,
                 "cat": cat or "default", "pid": 1,
-                "tid": self._tid(track), "ts": self._us(t),
-                "args": dict(args) if args else {}})
+                "tid": self._tid(track), "ts": self._us(t_ns),
+                "args": args})
+
+    def anchor(self) -> int:
+        """Stamp this tracer's clock onto the running ``jax.profiler``
+        trace (see the module docstring): a zero-length
+        ``obs.clock_anchor`` annotation with a ``perf_ns`` stat, and an
+        instant of the same name and ``perf_ns`` here.  Returns the
+        stamp.  Outside a profiler session the annotation goes nowhere
+        and only the instant is kept."""
+        from jax.profiler import TraceAnnotation   # stdlib at import
+        t = time.perf_counter_ns()
+        with TraceAnnotation(CLOCK_ANCHOR, perf_ns=t):
+            pass
+        self._emit_instant(CLOCK_ANCHOR, "obs", t, {"perf_ns": t}, None)
+        return t
 
     def counter(self, name: str, value: float,
                 track: Optional[str] = None) -> None:

@@ -65,10 +65,19 @@ class ServeLoop:
                                max_wait_us=max_wait_us, clock=clock)
         self._seq = 0
         self._admitted_at: Dict[int, float] = {}
-        # Wall-clock admission stamps for tracing only: the loop clock
-        # is injectable (tests drive fake clocks), so trace timestamps
-        # come from the tracer's perf_counter_ns clock instead.
+        # Tracer-clock admission stamps, taken only while tracing: the
+        # loop clock is injectable (tests drive fake clocks), so spans
+        # use the tracer's perf_counter_ns clock.  Each request gets
+        # ``serve.request`` (admission -> its completion stamp, taken
+        # when its record_response returns), split into
+        # ``serve.batching`` (admission -> the _dispatch that places its
+        # batch), ``serve.handoff`` (placement -> its overlay worker
+        # starts the batch) and ``serve.respond`` (execute_on returns ->
+        # completion).  Batching plus hand-off is the queue_wait_s
+        # interval.  Each span carries the request id and the batch's
+        # sequence number (``_batch_seq``, counted while tracing).
         self._admitted_ns: Dict[int, int] = {}
+        self._batch_seq = 0
         self._results: Dict[int, InferenceResponse] = {}
         self._pins: Dict[int, tuple] = {}    # idx -> (live server, vid)
         self._lock = threading.Lock()
@@ -114,9 +123,6 @@ class ServeLoop:
         tracer = get_tracer()
         if tracer.enabled:
             self._admitted_ns[idx] = tracer.now_ns()
-            tracer.instant("admit", cat="serve", track="queue",
-                           args={"request": req.request_id or f"#{idx}",
-                                 "depth": self.batcher.depth})
         if pin is not None:
             with self._lock:
                 self._pins[idx] = pin
@@ -154,26 +160,32 @@ class ServeLoop:
         # without bound; failed ones stay so drain() still raises
         self._futures = [f for f in self._futures
                          if not f.done() or f.exception() is not None]
+        tracer = get_tracer()
         for batch, overlay in zip(batches, placements):
             self.metrics.record_batch(batch.key, len(batch))
+            placed = None
+            if tracer.enabled:
+                placed = (self._batch_seq, tracer.now_ns())
+                self._batch_seq += 1
             if self._workers is not None:
                 self._futures.append(self._workers[overlay].submit(
-                    self._execute, batch, overlay))
+                    self._execute, batch, overlay, placed))
             else:
-                self._execute(batch, overlay)
+                self._execute(batch, overlay, placed)
 
-    def _execute(self, batch: Batch, overlay: int) -> None:
+    def _execute(self, batch: Batch, overlay: int,
+                 placed: Optional[tuple] = None) -> None:
+        """Run one batch on its overlay and record its responses.
+        ``placed`` is ``(batch sequence number, placement stamp)`` when
+        the batch was placed while tracing."""
         # Clocked at execution start, in the worker: the wait term then
         # covers batching delay AND time spent queued behind earlier
         # batches in this overlay's FIFO — the full experienced latency.
         started = self.clock()
         tracer = get_tracer()
-        start_ns = tracer.now_ns() if tracer.enabled else 0
-        bspan = tracer.span(
-            "batch", cat="serve", track=f"overlay{overlay}",
-            args={"key": batch.key[:12], "size": len(batch)})
+        start_ns = tracer.now_ns() if placed is not None else 0
         resps = self.pool.execute_on(overlay, batch)
-        bspan.add(cache_hit=bool(resps and resps[0].cache_hit)).done()
+        returned_ns = tracer.now_ns() if placed is not None else 0
         released = []
         with self._lock:
             for idx, r in zip(batch.indices, resps):
@@ -184,14 +196,19 @@ class ServeLoop:
                     queue_wait_s=wait, execute_s=r.t_loh,
                     compile_s=r.t_loc)
                 adm_ns = self._admitted_ns.pop(idx, None)
-                if adm_ns is not None:
-                    # Retroactive: admission stamped in the caller's
-                    # thread, closed here in the worker at batch start.
-                    tracer.complete(
-                        "queue_wait", adm_ns, start_ns, cat="serve",
-                        track="queue",
-                        args={"request": r.request_id,
-                              "overlay": overlay})
+                if placed is not None and adm_ns is not None:
+                    # Retroactive: admission and placement were stamped
+                    # in the caller's thread, the rest here.
+                    done_ns = tracer.now_ns()
+                    args = {"request": r.request_id, "batch": placed[0],
+                            "overlay": overlay}
+                    for name, t0, t1 in (
+                            ("serve.batching", adm_ns, placed[1]),
+                            ("serve.handoff", placed[1], start_ns),
+                            ("serve.respond", returned_ns, done_ns),
+                            ("serve.request", adm_ns, done_ns)):
+                        tracer.complete(name, t0, t1, cat="serve",
+                                        track="serve", args=args)
                 self._results[idx] = r
                 pin = self._pins.pop(idx, None)
                 if pin is not None:

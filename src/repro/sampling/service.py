@@ -124,19 +124,21 @@ class SamplingService:
         """sample -> normalize -> bucket -> lay out; no execution.
         ``count=False`` keeps warmup traffic out of the bucket census."""
         tracer = get_tracer()
-        with tracer.span("sample", cat="sample", track="sampling",
-                         args={"targets": len(req.targets)}) as sp:
+        with tracer.span("sampling.sample", cat="sample", track="sampling",
+                         args={"request": req.request_id}) as sp:
             ego = sample_ego(self.graph, req.targets, req.fanouts,
                              seed=req.seed)
             sub = self._normalize(ego.graph)
-            sp.add(n_vertices=sub.n_vertices, n_edges=sub.n_edges)
-        with tracer.span("layout", cat="sample", track="sampling") as sp:
+            sp.add(targets=len(req.targets), n_vertices=sub.n_vertices,
+                   n_edges=sub.n_edges)
+        with tracer.span("sampling.layout", cat="sample", track="sampling",
+                         args={"request": req.request_id}) as sp:
             bucket = bucket_for(sub, self.geometry)
             gd = layout_graph(sub, bucket, self.geometry)
+            feats = np.zeros((bucket.n_vertices, self.graph.feat_dim),
+                             np.float32)
+            feats[: ego.vertices.shape[0]] = self.features[ego.vertices]
             sp.add(bucket=bucket.key)
-        feats = np.zeros((bucket.n_vertices, self.graph.feat_dim),
-                         np.float32)
-        feats[: ego.vertices.shape[0]] = self.features[ego.vertices]
         if count:
             self.bucket_counts[bucket.key] = \
                 self.bucket_counts.get(bucket.key, 0) + 1

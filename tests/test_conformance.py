@@ -206,6 +206,7 @@ def traced_run():
     eng = Engine(geometry=GEOM, n_pes=4)
     prog = _compiled(eng, "b3", g)
     eng.run(prog, x, residency="host")          # warm (jit compiles)
+    eng._executor.profile_tiles = True          # the report's density join
     with tracing() as t:
         eng.run(prog, x, residency="host")
     return prog, eng, t.events()

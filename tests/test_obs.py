@@ -330,9 +330,25 @@ def test_record_cutover_version_skew():
 # --------------------------------------------------------------------------- #
 # ServeLoop lifecycle spans + phase split wiring.
 # --------------------------------------------------------------------------- #
+class _WaitRecorder(Metrics):
+    """Metrics that keep each response's queue_wait_s by request id."""
+
+    def __init__(self):
+        super().__init__()
+        self.waits = {}
+
+    def record_response(self, resp, latency_s, queue_wait_s=None,
+                        execute_s=None, compile_s=None):
+        super().record_response(resp, latency_s, queue_wait_s=queue_wait_s,
+                                execute_s=execute_s, compile_s=compile_s)
+        self.waits[resp.request_id] = queue_wait_s
+
+
 def test_serve_loop_emits_lifecycle_spans_and_phase_split():
     g = _g()
-    pool = OverlayPool(n_overlays=1, geometry=GEOM, n_pes=4)
+    metrics = _WaitRecorder()
+    pool = OverlayPool(n_overlays=1, geometry=GEOM, n_pes=4,
+                       metrics=metrics)
     loop = ServeLoop(pool, max_batch=4)
     x = jnp.asarray(G.random_features(g, seed=1))
     reqs = [InferenceRequest(model="b1", graph=g, features=x,
@@ -340,19 +356,184 @@ def test_serve_loop_emits_lifecycle_spans_and_phase_split():
     with tracing() as t:
         resps = loop.serve(reqs)
     assert len(resps) == 4
-    evs = t.events()
-    admits = [e for e in evs if e["name"] == "admit" and e["ph"] == "i"]
-    waits = [e for e in evs
-             if e["name"] == "queue_wait" and e["ph"] == "X"]
-    batches = [e for e in evs if e["name"] == "batch" and e["ph"] == "X"]
-    assert len(admits) == 4 and len(waits) == 4 and batches
-    assert {w["args"]["request"] for w in waits} \
-        == {"q0", "q1", "q2", "q3"}
+    spans = [e for e in t.events() if e["ph"] == "X"]
+    names = ("serve.batching", "serve.handoff", "serve.respond",
+             "serve.request")
+    by = {n: {} for n in names}
+    for e in spans:
+        if e["name"] in by:
+            rid = e["args"]["request"]
+            assert rid not in by[e["name"]], f"two {e['name']} for {rid}"
+            by[e["name"]][rid] = e
+    ids = {"q0", "q1", "q2", "q3"}
+    for n in names:
+        assert set(by[n]) == ids, n
+    # The replaced spans are gone.
+    assert not {"admit", "queue_wait", "batch"} & {
+        e["name"] for e in t.events()}
+    for rid in ids:
+        b, h, r, q = (by[n][rid] for n in names)
+        # one batch per request, shared by its spans
+        assert b["args"]["batch"] == h["args"]["batch"] \
+            == r["args"]["batch"] == q["args"]["batch"]
+        # batching ends where hand-off starts; the request spans both
+        # and ends with the response
+        assert b["ts"] + b["dur"] == pytest.approx(h["ts"], abs=2e-3)
+        assert q["ts"] == pytest.approx(b["ts"], abs=1e-3)
+        assert q["ts"] + q["dur"] == pytest.approx(r["ts"] + r["dur"],
+                                                   abs=1e-3)
+        assert h["ts"] + h["dur"] <= r["ts"] + 1e-3
+        # batching + hand-off is the loop's own queue wait (µs vs s)
+        assert (b["dur"] + h["dur"]) * 1e-6 == pytest.approx(
+            metrics.waits[rid], abs=50e-6)
     # Metrics got the wait-vs-execute split from the same code path.
     snap = pool.metrics.snapshot()["global"]
     assert "queue_wait_ms" in snap and "execute_ms" in snap
     assert len(pool.metrics.slowest(10)) == 4
     loop.shutdown()
+
+
+def test_serve_loop_records_no_stamps_without_tracing():
+    g = _g()
+    pool = OverlayPool(n_overlays=1, geometry=GEOM, n_pes=4)
+    loop = ServeLoop(pool, max_batch=4)
+    x = jnp.asarray(G.random_features(g, seed=1))
+    loop.serve([InferenceRequest(model="b1", graph=g, features=x)
+                for _ in range(3)])
+    assert loop._admitted_ns == {} and loop._batch_seq == 0
+    loop.shutdown()
+
+
+def test_batch_spans_cover_the_overlay_execution():
+    """Between a batch's hand-off and its responses, the engine's
+    ``exec.batch_stage``, ``exec.batch_pass`` and ``exec.batch_unstack``
+    follow one another and leave next to nothing unspanned."""
+    g = _g()
+    pool = OverlayPool(n_overlays=1, geometry=GEOM, n_pes=4)
+    loop = ServeLoop(pool, max_batch=2)
+    x = jnp.asarray(G.random_features(g, seed=1))
+    reqs = [InferenceRequest(model="b1", graph=g, features=x,
+                             request_id=f"q{i}") for i in range(4)]
+    loop.serve(reqs[:2])        # compile outside the traced part
+    with tracing() as t:
+        loop.serve(reqs)
+    loop.shutdown()
+    spans = [e for e in t.events() if e["ph"] == "X"]
+    names = ("exec.batch_stage", "exec.batch_pass", "exec.batch_unstack")
+    execs = [e for e in spans if e["name"] in names]
+    assert [e["name"] for e in execs] == list(names) * 2
+    windows = {}
+    for e in spans:
+        if e["name"] == "serve.handoff":
+            windows[e["args"]["batch"]] = [e["ts"] + e["dur"]]
+        elif e["name"] == "serve.respond":
+            windows[e["args"]["batch"]].append(e["ts"])
+    assert len(windows) == 2
+    for i, (a, b) in enumerate(sorted(windows.values())):
+        stage, pas, unstack = execs[3 * i: 3 * i + 3]
+        assert a <= stage["ts"] + 1e-3
+        assert stage["ts"] + stage["dur"] <= pas["ts"] + 1e-3
+        assert pas["ts"] + pas["dur"] <= unstack["ts"] + 1e-3
+        assert unstack["ts"] + unstack["dur"] <= b + 1e-3
+        assert pas["args"] == {"requests": 2, "lanes": 2}
+        uncovered = (b - a) - sum(e["dur"] for e in (stage, pas, unstack))
+        assert uncovered <= max(1e3, 0.2 * (b - a))     # µs
+
+
+# --------------------------------------------------------------------------- #
+# One clock: tracer spans laid on a jax.profiler trace through anchor().
+# --------------------------------------------------------------------------- #
+def _profile_events(log_dir, names):
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in names:
+                    out.append((ev.name, int(ev.start_ns),
+                                int(ev.duration_ns), dict(ev.stats)))
+    return sorted(out, key=lambda e: e[1])
+
+
+def test_anchor_puts_tracer_spans_on_the_profiler_clock(tmp_path):
+    import time
+    import jax
+    from repro.obs.tracer import CLOCK_ANCHOR
+    t = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        t.anchor()
+        time.sleep(0.02)
+        with jax.profiler.TraceAnnotation("probe"):
+            with t.span("probe"):
+                time.sleep(0.03)
+        time.sleep(0.02)
+        t.anchor()
+    finally:
+        jax.profiler.stop_trace()
+    evs = _profile_events(str(tmp_path), {CLOCK_ANCHOR, "probe"})
+    anchors = [e for e in evs if e[0] == CLOCK_ANCHOR]
+    probe, = [e for e in evs if e[0] == "probe"]
+    assert len(anchors) == 2
+    # The tracer's own instants carry the same perf_ns stamps.
+    mine = [e for e in t.events() if e["name"] == CLOCK_ANCHOR]
+    assert [int(a[3]["perf_ns"]) for a in anchors] \
+        == [e["args"]["perf_ns"] for e in mine]
+    # Map the tracer span through the anchors: a line through both.
+    (_, y0, _, s0), (_, y1, _, s1) = anchors
+    x0, x1 = int(s0["perf_ns"]), int(s1["perf_ns"])
+    slope = (y1 - y0) / (x1 - x0)
+    span, = [e for e in t.events() if e["name"] == "probe"]
+    # tracer ts (µs from its start) -> perf_counter_ns via an anchor
+    base = mine[0]["args"]["perf_ns"] - mine[0]["ts"] * 1e3
+    start = y0 + slope * (base + span["ts"] * 1e3 - x0)
+    end = start + slope * span["dur"] * 1e3
+    assert abs(start - probe[1]) < 1e6           # within 1 ms
+    assert abs(end - (probe[1] + probe[2])) < 1e6
+    # zero-length anchors, and a drift far below the window
+    assert abs(slope - 1.0) < 1e-2
+
+
+def test_null_tracer_anchor_is_a_noop():
+    assert NullTracer().anchor() == 0
+
+
+# --------------------------------------------------------------------------- #
+# Stable device names: the batched pass and the ACK modes' scopes.
+# --------------------------------------------------------------------------- #
+def test_batched_pass_is_named_and_scoped_by_ack_mode():
+    from repro.engine.executor import device_tiles, reads_edges
+    g = _g()
+    x = jnp.asarray(G.random_features(g, seed=1))
+    eng = Engine(geometry=GEOM, n_pes=4)
+    prog = eng.compile("b1", g)
+    xs = jnp.stack([x, x])
+    eng.run_batch(prog, xs)
+    (fn, _), = prog.__dict__["_batch_exec"].values()
+    gd = device_tiles(prog.pgraph, edges=reads_edges(prog.plan()))
+    text = fn.lower(xs, gd).as_text(debug_info=True)
+    assert "@jit_batched_pass" in text
+    assert "ack.spdmm" in text and "ack.gemm" in text
+
+
+def test_tracing_leaves_tile_profile_off_and_mutes_the_jit_trace():
+    g = _g()
+    x = jnp.asarray(G.random_features(g, seed=1))
+    eng = Engine(geometry=GEOM, n_pes=4)
+    prog = eng.compile("b1", g)
+    with tracing() as t:
+        eng.run_batch(prog, jnp.stack([x, x]))      # traces run()
+        eng.run(prog, x)                            # eager: spans kept
+    assert "exec_profile" not in prog.manifest
+    names = [e["name"] for e in t.events() if e["ph"] == "X"]
+    # one decode and one span per layer, all from the eager run
+    assert names.count("decode") == 1
+    layers = [n for n in names if n.startswith("layer")]
+    assert len(layers) == len(prog.plan().layers)
 
 
 # --------------------------------------------------------------------------- #
