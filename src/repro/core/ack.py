@@ -155,10 +155,14 @@ def _sddmm_pair_xla(h_dst, h_src, cols, mask, acc):
     return acc + jnp.where(mask, part, 0.0)
 
 
-@jax.jit
+# The coefficients are static: per-tile dispatch and a layer traced into
+# one executable then fold the same constants (a 1.0 drops its multiply)
+# and contract the same multiply into an FMA, so both give the same
+# bits.
+@functools.partial(jax.jit, static_argnames=("alpha", "beta"))
 @_mode("vadd")
-def _vadd_xla(a, b, alpha, beta):
-    return alpha * a + beta * b
+def _vadd_xla(a, b, alpha: float, beta: float):
+    return jnp.float32(alpha) * a + jnp.float32(beta) * b
 
 
 @functools.partial(jax.jit, static_argnames=("act",))
@@ -229,7 +233,7 @@ class ACK:
     # -- Vector addition / epilogues ------------------------------------ #
     def vadd(self, a, b, alpha: float, beta: float):
         _count(("vadd", a.shape, self.backend))
-        return _vadd_xla(a, b, jnp.float32(alpha), jnp.float32(beta))
+        return _vadd_xla(a, b, alpha=float(alpha), beta=float(beta))
 
     def act(self, x, act: Activation):
         _count(("act", x.shape, int(act)))

@@ -493,6 +493,9 @@ class Engine:
         assigned destination shards under ``shard_map``, exchanging halo
         sub-fibers with collectives.  Results are bit-identical across
         all three; ``None`` uses the program's compile-time default.
+        A device-resident pass is traced once per program and argument
+        shapes into one jitted executable per layer and replayed after
+        that (``exec_stats.pass_compiles`` / ``pass_replays``).
 
         ``graph`` (a live-versioned graph or ``repro.livegraph``
         handle) rebinds the program to that version's patched tiles

@@ -18,11 +18,14 @@ A version owns the executor-facing views of its snapshot:
     CSR view builds from;
   * ``bind(prog)``  — rebind a structurally-matching compiled program
     to this version's tiles.  The bound copy is cached per program
-    cache key: it is a fresh object (so the executor's per-program jit
-    memo cannot replay executables that baked older tiles in as
-    constants) but a *stable* one (so steady-state batched traffic on
-    one version still reuses its jitted executable).  Its manifest is a
-    shallow copy carrying this version's ``tile_stats`` and graph name.
+    cache key: it is a fresh object (so the executor's per-program
+    batched-pass memo cannot replay executables that baked older tiles
+    in as constants) but a *stable* one (so steady-state batched traffic
+    on one version still reuses its jitted executable).  It shares the
+    program's device-pass memo, whose executables take tiles as
+    arguments: a version whose tiles keep their shapes replays them.
+    Its manifest is a shallow copy carrying this version's
+    ``tile_stats`` and graph name.
 
 The store is NOT the serving cutover mechanism — that is
 ``livegraph.swap.LiveGraphServer``, which pins versions across request
@@ -36,6 +39,7 @@ from typing import Dict, Optional, Tuple
 
 from repro.core.graph import Graph
 from repro.core.passes.partition import PartitionConfig
+from repro.engine.executor import share_pass_executables
 
 from .delta import GraphDelta
 from .tiles import PatchStats, TileStore, tile_density_stats
@@ -130,6 +134,7 @@ class GraphVersion:
                 source=None)
             if manifest.get("remap") is not None:
                 bound = self._rebind_remap(bound)
+            share_pass_executables(prog, bound)
             self._bound[key] = (prog.binary, bound)
             return bound
 

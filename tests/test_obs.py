@@ -527,10 +527,22 @@ def test_tracing_leaves_tile_profile_off_and_mutes_the_jit_trace():
     prog = eng.compile("b1", g)
     with tracing() as t:
         eng.run_batch(prog, jnp.stack([x, x]))      # traces run()
-        eng.run(prog, x)                            # eager: spans kept
+        eng.run(prog, x)                            # traces the pass
+        eng.run(prog, x)                            # replays it
     assert "exec_profile" not in prog.manifest
     names = [e["name"] for e in t.events() if e["ph"] == "X"]
-    # one decode and one span per layer, all from the eager run
+    # one decode a single pass, and no span from either trace
+    assert names.count("decode") == 2
+    assert not [n for n in names if n.startswith("layer")]
+    counters = {e["name"]: e["args"]["value"] for e in t.events()
+                if e["ph"] == "C"}
+    assert counters == {"exec.pass_compiles": 1, "exec.pass_replays": 1}
+    # per-tile dispatch keeps one decode and one span per layer
+    eager = Engine(geometry=GEOM, n_pes=4, overlap=False)
+    prog = eager.compile("b1", g)
+    with tracing() as t:
+        eager.run(prog, x)
+    names = [e["name"] for e in t.events() if e["ph"] == "X"]
     assert names.count("decode") == 1
     layers = [n for n in names if n.startswith("layer")]
     assert len(layers) == len(prog.plan().layers)
