@@ -6,8 +6,12 @@ replay must give the bits of per-tile dispatch (``overlap=False``) and
 of host streaming, take new weights and rebound tiles of unchanged
 shapes as arguments without a new trace, compile once more when a shape
 changes, free outputs as the eager pass does, and name each layer's
-executable after its ACK mode, whose scope it keeps.
+executable after its ACK mode, whose scope it keeps, or an edge-valued
+activation after what it computes, counting the pass's scatters into
+the global edge array.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,12 +20,14 @@ import pytest
 from repro.core import ack
 from repro.core import gnn_builders as B
 from repro.core import graph as G
+from repro.core.ir import LayerType
 from repro.core.passes.partition import PartitionConfig
 from repro.engine import Engine
 from repro.engine.executor import (_KERNEL_MODES, device_tiles,
                                    reads_edges)
 from repro.livegraph import (GraphDelta, GraphVersionStore,
                              LiveGraphServer, as_graph_data)
+from repro.obs import NullTracer, tracing
 
 GEOM = PartitionConfig(n1=32, n2=8)
 
@@ -149,6 +155,52 @@ def test_layers_are_named_and_scoped_by_ack_mode():
                 prog.weights).as_text(debug_info=True)
             assert f"@jit_{modes[t]}" in text
             assert f"ack.{modes[t]}" in text
+
+
+def test_edge_layers_are_named_and_their_scatters_counted():
+    """GAT (b6) scores edges in ``jit_sddmm``, under the ``ack.sddmm``
+    scope, and normalizes them in ``jit_edge_softmax``, apart from a
+    vertex activation's ``jit_act``; every edge-valued tile scatters
+    into the global edge array once a pass, on the jitted and the eager
+    path alike, and GCN (b2) scatters nothing."""
+    g = _g(seed=3)
+    x = jnp.asarray(G.random_features(g, seed=1))
+    eng = _engine()
+    prog = eng.compile("b6", g)
+    with tracing() as tr:
+        eng.run(prog, x)
+        eng.run(prog, x)
+    plan = prog.plan()
+    entry, = prog.__dict__["_pass_exec"].values()
+    ex, texts = eng._executor, []
+    with entry.called_by(ex, prog) as fns:
+        def lowered(prog, t, *args):
+            texts.append(fns[t].lower(*args).as_text(debug_info=True))
+            return fns[t](*args)
+        ex._layers(prog, plan, x, device_tiles(prog.pgraph),
+                   prog.weights, NullTracer(), lowered)
+    names = [re.search(r"module @(\w+)", t).group(1) for t in texts]
+    assert names == 2 * ["jit_gemm", "jit_gemm", "jit_sddmm",
+                         "jit_edge_softmax", "jit_spdmm"]
+    assert all("ack.sddmm" in t for n, t in zip(names, texts)
+               if n == "jit_sddmm")
+
+    slices = sum(len(ts) for ts in prog.pgraph.tiles.values())
+    edge_tiles = sum(len(lp.tiles) for lp in plan.layers
+                     if lp.layer_type == LayerType.VECTOR_INNER
+                     or lp.on_edges)
+    assert edge_tiles == 2 * 2 * slices     # two layers: SDDMM, softmax
+    counted = [e["args"]["value"] for e in tr.events()
+               if e["ph"] == "C" and e["name"] == "exec.edge_scatters"]
+    assert counted == [edge_tiles, edge_tiles]
+    assert eng.exec_stats.edge_scatters == edge_tiles
+    assert eng.exec_stats_total.edge_scatters == 2 * edge_tiles
+    eager = _engine(overlap=False)
+    eager.run(eager.compile("b6", g), x)
+    assert eager.exec_stats.edge_scatters == edge_tiles
+    gcn = _engine()
+    gcn.run(gcn.compile("b2", g), x)
+    assert gcn.exec_stats.edge_scatters == 0
 
 
 def test_replayed_layers_free_outputs_as_the_eager_pass_does():

@@ -536,7 +536,8 @@ def test_tracing_leaves_tile_profile_off_and_mutes_the_jit_trace():
     assert not [n for n in names if n.startswith("layer")]
     counters = {e["name"]: e["args"]["value"] for e in t.events()
                 if e["ph"] == "C"}
-    assert counters == {"exec.pass_compiles": 1, "exec.pass_replays": 1}
+    assert counters == {"exec.pass_compiles": 1, "exec.pass_replays": 1,
+                        "exec.edge_scatters": 0}
     # per-tile dispatch keeps one decode and one span per layer
     eager = Engine(geometry=GEOM, n_pes=4, overlap=False)
     prog = eager.compile("b1", g)
