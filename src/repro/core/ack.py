@@ -107,6 +107,18 @@ def _spdmm_xla(h_src, cols, vals, mask, acc, flag, op: str):
     raise ValueError(op)
 
 
+# SpDMM over a slice's edge list (``ELLTile.edges``): SUM and MEAN read
+# the real edges only, in destination order, instead of the n1 x width
+# ELL slots.  The gathered rows are [m, n2] for a list of m entries, and
+# pad entries carry val 0 on the last row, so they add exactly 0.  The
+# scatter takes no sorted-indices hint: a caller's list is not checked.
+@jax.jit
+@_mode("spdmm")
+def _spdmm_edges_xla(h_src, dst, src, vals, acc):
+    msg = h_src[src] * vals[:, None]             # [m, n2]
+    return acc.at[dst].add(msg)
+
+
 # --------------------------------------------------------------------------- #
 # Dense-aggregate GEMM: densified SpDMM for remapped high-density tiles
 # (Dynasparse-style sparsity-adaptive mode switch).  The ELL tile is
@@ -216,6 +228,12 @@ class ACK:
                              else flag | mask.any(axis=1))
             self.fallbacks += 1
         return _spdmm_xla(h_src, cols, vals, mask, acc, flag, op)
+
+    def spdmm_edges(self, h_src, dst, src, vals, acc):
+        """SUM/MEAN SpDMM over an edge list (xla backend only: a Pallas
+        executor aggregates ELL slices)."""
+        _count(("spdmm", h_src.shape, dst.shape, "edges", self.backend))
+        return _spdmm_edges_xla(h_src, dst, src, vals, acc)
 
     # -- SDDMM ---------------------------------------------------------- #
     def sddmm(self, h_dst, h_src, cols, mask, acc, pair_sum: bool = False):

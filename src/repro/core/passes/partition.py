@@ -15,6 +15,7 @@ row-ownership replaces the banked-SRAM shuffle networks.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Dict, List, Tuple
 
@@ -23,6 +24,7 @@ import numpy as np
 from ..graph import Graph
 
 LANE = 8  # pad max-nnz to a multiple of this (TPU sublane friendliness)
+EDGE_LIST_MIN = 128  # shortest edge list; longer ones are powers of two
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,6 +79,53 @@ class ELLTile:
     def width(self) -> int:
         return int(self.cols.shape[1])
 
+    @functools.cached_property
+    def edges(self) -> Dict[str, np.ndarray]:
+        """The slice's real slots as an edge list in destination order
+        (row-major over the ELL slots, so a row keeps its slot order):
+        ``dst`` local row, ``src`` local source column, ``val`` the
+        slot's value, ``eid`` its global edge id.  The length is a power
+        of two, at least ``EDGE_LIST_MIN``, so few shapes recur; pad
+        entries point at the last row with value 0 and edge id -1, so
+        they add exactly 0 and keep the list sorted.  Derived once per
+        tile, on first use."""
+        r, c = np.nonzero(self.edge_pos >= 0)
+        out = empty_edge_list(self.cols.shape[0], list_length(r.size))
+        out["dst"][:r.size] = r
+        out["src"][:r.size] = self.cols[r, c]
+        out["val"][:r.size] = self.vals[r, c]
+        out["eid"][:r.size] = self.edge_pos[r, c]
+        return out
+
+    def field(self, name: str) -> np.ndarray:
+        """One of the slice's arrays by its ``graph_data`` name (see
+        ``TILE_FIELDS``): an ELL plane, or ``edges.<key>`` of its list."""
+        if name.startswith("edges."):
+            return self.edges[name[len("edges."):]]
+        return {"cols": self.cols, "vals": self.vals,
+                "epos": self.edge_pos}[name]
+
+
+# The arrays a slice can be placed as, by their ``graph_data`` names.
+ELL_FIELDS = ("cols", "vals", "epos")
+TILE_FIELDS = ELL_FIELDS + ("edges.dst", "edges.src", "edges.val",
+                            "edges.eid")
+
+
+def list_length(m: int) -> int:
+    """The length of an edge list holding ``m`` edges: a power of two,
+    at least ``EDGE_LIST_MIN``."""
+    return max(EDGE_LIST_MIN, 1 << max(int(m) - 1, 0).bit_length())
+
+
+def empty_edge_list(n1: int, length: int) -> Dict[str, np.ndarray]:
+    """An edge list of ``length`` pad entries: last row, source 0,
+    value 0, edge id -1."""
+    return {"dst": np.full(length, n1 - 1, np.int32),
+            "src": np.zeros(length, np.int32),
+            "val": np.zeros(length, np.float32),
+            "eid": np.full(length, -1, np.int32)}
+
 
 @dataclasses.dataclass
 class PartitionedGraph:
@@ -90,9 +139,11 @@ class PartitionedGraph:
     # For MEAN aggregation: 1/in-degree per vertex (padded length).
     inv_in_degree: np.ndarray
 
-    def tile_bytes(self) -> int:
-        return sum(t.cols.nbytes + t.vals.nbytes + t.edge_pos.nbytes
-                   for ts in self.tiles.values() for t in ts)
+    def tile_bytes(self, fields=ELL_FIELDS) -> int:
+        """Bytes of the slices' arrays named in ``fields`` (by default
+        the ELL planes)."""
+        return sum(t.field(f).nbytes for ts in self.tiles.values()
+                   for t in ts for f in fields)
 
     def total_nnz(self) -> int:
         return sum(t.nnz for ts in self.tiles.values() for t in ts)

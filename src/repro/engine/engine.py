@@ -47,7 +47,8 @@ from repro.core.passes.partition import PartitionConfig
 from repro.obs.tracer import get_tracer
 
 from .cache import LRUCache
-from .executor import BinaryExecutor, ExecStats, ensure_placement
+from .executor import (BinaryExecutor, ExecStats, ensure_placement,
+                       select_tiles)
 from .program import CompiledProgram, from_program
 
 ModelSpec = Union[str, ModelIR]
@@ -215,9 +216,9 @@ def stack_features(features: Sequence[Any]) -> "jax.Array":
 def stack_graph_data(gds: Sequence[dict], pad_to: int) -> dict:
     """Stack N per-request ``graph_data`` pytrees (identical structure —
     one geometry bucket) into a leading batch axis, zero-filling up to
-    ``pad_to`` lanes.  Zero lanes are inert: mask False everywhere, so
-    padded lanes compute on empty graphs and their outputs are sliced
-    off with the feature padding."""
+    ``pad_to`` lanes.  Zero lanes are inert: mask False and edge-list
+    values 0 everywhere, so padded lanes compute on empty graphs and
+    their outputs are sliced off with the feature padding."""
     stacked = jax.tree_util.tree_map(
         lambda *a: jnp.stack([jnp.asarray(x) for x in a]), *gds)
     extra = pad_to - len(gds)
@@ -681,8 +682,10 @@ class Engine:
         bucket = 1 << (n - 1).bit_length()
         if bucket != n:
             xs = jnp.pad(xs, ((0, bucket - n), (0, 0), (0, 0)))
-        gd = stack_graph_data([r.graph_data for r in reqs], bucket) \
-            if with_gd else None
+        # only the tile arrays the program reads are stacked and shipped
+        fields = self._executor._tile_fields(prog)
+        gd = stack_graph_data([select_tiles(r.graph_data, fields)
+                               for r in reqs], bucket) if with_gd else None
         stage.done()
         t0 = time.perf_counter_ns()
         ys = self.run_batch(prog, xs, graph_data=gd)[:n]

@@ -48,13 +48,23 @@ canonical template, ``repro.sampling.buckets``), and each request ships
 its actual topology as arrays matching the canonical layout::
 
     {"tiles": {"j:k:s": {"cols": int32 [n1, w], "vals": float32 [n1, w],
-                         "mask": bool  [n1, w], "epos": int32  [n1, w]}},
+                         "mask": bool  [n1, w], "epos": int32  [n1, w],
+                         "edges": {"dst": int32 [m], "src": int32 [m],
+                                   "val": float32 [m], "eid": int32 [m]}}},
      "inv_in_degree": float32 [nb * n1]}
 
 ``epos`` uses the same convention as the baked tiles (original COO edge
-index, ``-1`` on pad slots).  In ``run_batch`` the structure is stacked
-with a leading batch axis and vmapped together with the features, so N
-*different* subgraphs sharing one bucket execute as ONE binary pass.
+index, ``-1`` on pad slots).  ``edges`` is optional: the slice's edge
+list (``ELLTile.edges``), which SUM/MEAN aggregation reads in place of
+the ELL slots where a tile carries it.  A list holds the slice's real
+slots in row-major order, so ``dst`` never decreases, then pad entries
+on the last row (``n1 - 1``) with value 0 and edge id -1; its length is
+the caller's choice (``ELLTile.edges``: a power of two; a sampled
+bucket: one length per bucket).  Only the arrays the program reads
+(:func:`tile_fields`) enter a pass.  In ``run_batch`` the structure
+is stacked with a leading batch axis and vmapped together with the
+features, so N *different* subgraphs sharing one bucket execute as ONE
+binary pass.
 """
 from __future__ import annotations
 
@@ -72,6 +82,7 @@ import numpy as np
 from repro.core.ack import ACK, densify_tile
 from repro.core.ir import Activation, AggOp, LayerType
 from repro.core.isa import Opcode
+from repro.core.passes.partition import ELL_FIELDS, TILE_FIELDS
 from repro.core.reference import apply_activation
 from repro.obs.tracer import NullTracer, get_tracer
 
@@ -105,7 +116,17 @@ def _tile_arrays(pg, gtiles, j: int, k: int, s: int):
     epos = d.get("epos")
     mask = d["mask"] if "mask" in d else (
         None if epos is None else epos >= 0)
-    return d["cols"], d["vals"], mask, epos
+    return d.get("cols"), d.get("vals"), mask, epos
+
+
+def _tile_edges(pg, gtiles, j: int, k: int, s: int) -> Optional[dict]:
+    """The edge list of tile (j, k, s) (``ELLTile.edges``), from
+    ``gtiles`` when given, else the baked host tile; ``None`` where the
+    ``graph_data`` tile carries none, so the slice is aggregated over
+    its ELL slots."""
+    if gtiles is None:
+        return pg.tiles[(j, k)][s].edges
+    return gtiles[f"{j}:{k}:{s}"].get("edges")
 
 
 def _edge_act(lp: LayerPlan) -> bool:
@@ -127,45 +148,88 @@ def _exec_name(lp: LayerPlan) -> str:
     return _KERNEL_MODES[lp.layer_type]
 
 
-def reads_edges(plan) -> bool:
-    """Whether a decoded program reads per-edge ids or pad masks: edge
-    scoring, edge activations, or MAX/MIN aggregation (row flags).
-    SUM/MEAN aggregation over static weights needs neither — pad slots
-    carry weight 0."""
-    return any(
-        lp.layer_type == LayerType.VECTOR_INNER or lp.on_edges
-        or (lp.layer_type == LayerType.AGGREGATE
-            and AggOp(lp.mode) in (AggOp.MAX, AggOp.MIN))
-        for lp in plan.layers)
+def tile_fields(plan, backend: str) -> Tuple[str, ...]:
+    """The per-slice arrays a decoded program reads, by their
+    ``graph_data`` names (``TILE_FIELDS``): SUM/MEAN SpDMM steps on the
+    xla backend read the slice's edge list (``edges.val``, or
+    ``edges.eid`` where the weights are per-edge values); every other
+    aggregation step reads ELL planes: ``cols`` and ``vals``, and
+    ``epos`` (the pad mask and edge ids) for MAX/MIN row flags and
+    per-edge weights; edge scoring and edge activations read ``cols``
+    and ``epos``.  Only these are placed on the device and enter a
+    pass."""
+    need = set()
+    for lp in plan.layers:
+        if lp.layer_type == LayerType.VECTOR_INNER or lp.on_edges:
+            need |= {"cols", "epos"}
+        if lp.layer_type != LayerType.AGGREGATE:
+            continue
+        lists = backend == "xla" and AggOp(lp.mode) in (AggOp.SUM,
+                                                          AggOp.MEAN)
+        for tp in lp.tiles:
+            for ins in tp.compute:
+                dyn = ins.args[3] & 1
+                if lists and ins.op != Opcode.GEMM:
+                    need |= {"edges.dst", "edges.src",
+                             "edges.eid" if dyn else "edges.val"}
+                else:
+                    need |= {"cols", "vals"}
+                    if dyn or not lists:
+                        need.add("epos")
+    return tuple(f for f in TILE_FIELDS if f in need)
 
 
-_PLACED_FIELDS = {"cols": "cols", "vals": "vals", "epos": "edge_pos"}
+def select_tiles(graph_data: dict, fields) -> dict:
+    """``graph_data`` with each tile cut to the arrays in ``fields``
+    (:func:`tile_fields`); a tile's ``mask`` goes with its ``epos``."""
+    keep = set(fields) | ({"mask"} if "epos" in fields else set())
+    listed = [f[len("edges."):] for f in fields if f.startswith("edges.")]
+    # a tile without a list is aggregated over its ELL slots instead
+    fallback = keep | {"cols", "vals"} | (
+        {"epos", "mask"} if "eid" in listed else set())
+
+    def cut(d):
+        if listed and "edges" not in d:
+            return {f: a for f, a in d.items() if f in fallback}
+        out = {f: a for f, a in d.items() if f in keep}
+        if listed:
+            out["edges"] = {f: d["edges"][f] for f in listed}
+        return out
+    return {"tiles": {key: cut(d) for key, d in graph_data["tiles"].items()},
+            "inv_in_degree": graph_data["inv_in_degree"]}
 
 
-def device_tiles(pg, edges: bool = True) -> dict:
-    """The partitioned graph's ELL tiles and inverse in-degrees on the
+def device_tiles(pg, fields) -> dict:
+    """The partitioned graph's slices and inverse in-degrees on the
     default device, in the ``graph_data`` layout (``mask`` derived from
-    ``epos`` on use).  Each array is placed once per graph and kept (every
-    program copy and livegraph version sharing the graph shares it);
-    ``edges=False`` leaves the edge ids on the host for programs that
-    never read them (see :func:`reads_edges`).  The device-resident path
-    reads its tiles from here and both jitted passes take them as
-    arguments, so tiles are neither shipped per tile op nor baked into
-    an executable as constants."""
+    ``epos`` on use), holding the arrays named in ``fields`` — a
+    program's :func:`tile_fields`, so ELL planes or edge lists that its
+    pass never reads stay on the host.  Each array is placed once per
+    graph and kept (every program copy and livegraph version sharing
+    the graph shares it).  The device-resident path reads its tiles
+    from here and both jitted passes take them as arguments, so tiles
+    are neither shipped per tile op nor baked into an executable as
+    constants."""
     placed = pg.__dict__.setdefault("_device_tiles", {})
-    fields = ("cols", "vals", "epos") if edges else ("cols", "vals")
+    keys = [(f"{j}:{k}:{s}", t) for (j, k), ts in pg.tiles.items()
+            for s, t in enumerate(ts)]
     with jax.ensure_compile_time_eval():
         for f in fields:
             if f not in placed:
-                placed[f] = {
-                    f"{j}:{k}:{s}": jax.device_put(
-                        getattr(t, _PLACED_FIELDS[f]))
-                    for (j, k), ts in pg.tiles.items()
-                    for s, t in enumerate(ts)}
+                placed[f] = {key: jax.device_put(t.field(f))
+                             for key, t in keys}
         if "inv" not in placed:
             placed["inv"] = jax.device_put(pg.inv_in_degree)
-    return {"tiles": {key: {f: placed[f][key] for f in fields}
-                      for key in placed["cols"]},
+    ell = [f for f in fields if f in ELL_FIELDS]
+    listed = [f for f in fields if f not in ELL_FIELDS]
+
+    def tile(key):
+        d = {f: placed[f][key] for f in ell}
+        if listed:
+            d["edges"] = {f[len("edges."):]: placed[f][key]
+                          for f in listed}
+        return d
+    return {"tiles": {key: tile(key) for key, _ in keys},
             "inv_in_degree": placed["inv"]}
 
 
@@ -282,6 +346,9 @@ class ExecStats:
     # the mesh leave it 0): each edge-scoring (SDDMM) tile and each edge
     # softmax tile writes its edge values back by edge id.
     edge_scatters: int = 0
+    # Source rows the SpDMM tile ops of one pass gather, padding
+    # included: an edge list's length, or n1 x width for an ELL slice.
+    spdmm_rows: int = 0
     # Per-decoded-layer attribution, populated on every residency path:
     # {"layer","kernel","step","instr_lo","instr_hi","wall_s","tile_ops",
     #  + path extras ("h2d_bytes" host, "halo_gather_bytes" mesh)}.
@@ -312,6 +379,7 @@ class ExecStats:
         self.pass_compiles += other.pass_compiles
         self.pass_replays += other.pass_replays
         self.edge_scatters += other.edge_scatters
+        self.spdmm_rows += other.spdmm_rows
         if other.tile_ops_by_mode is not None:
             for m, n in other.tile_ops_by_mode.items():
                 self.note_mode(m, n)
@@ -499,10 +567,10 @@ def ensure_placement(prog: CompiledProgram, n_devices: int) -> dict:
 # Operand environments — where a tile's operands come FROM.
 #
 # A kernel's tile computation is identical on every path; only operand
-# residency differs.  Each environment answers the same five questions:
-# a feature tile of source block k / fiber i, a named vector-add operand
-# tile, a graph (ELL) tile, the per-edge dynamic weights of a tile, and
-# the inverse-degree slice of a destination block.
+# residency differs.  Each environment answers the same questions: a
+# feature tile of source block k / fiber i, a named vector-add operand
+# tile, a graph (ELL) tile and its edge list, the per-edge dynamic
+# weights of either, and the inverse-degree slice of a destination block.
 # --------------------------------------------------------------------------- #
 class _DeviceEnv:
     """Device-resident path: whole padded arrays live on device; tiles
@@ -529,6 +597,13 @@ class _DeviceEnv:
     def edge_weight_tile(self, j: int, k: int, s: int):
         _, _, mask, epos = self.graph_tile(j, k, s)
         return jnp.where(mask, self.ew[jnp.maximum(epos, 0)], 0.0)
+
+    def graph_edges(self, j: int, k: int, s: int):
+        return _tile_edges(self.pg, self.gtiles, j, k, s)
+
+    def edge_weight_list(self, j: int, k: int, s: int):
+        eid = self.graph_edges(j, k, s)["eid"]
+        return jnp.where(eid >= 0, self.ew[jnp.maximum(eid, 0)], 0.0)
 
     def inv_deg_tile(self, j: int):
         return jax.lax.dynamic_slice(self.inv, (j * self.n1,), (self.n1,))
@@ -560,6 +635,16 @@ class _HostEnv:
     def edge_weight_tile(self, j: int, k: int, s: int):
         return jnp.where(self.staged[f"m{k}:{s}"],
                          self.staged[f"{self.pre}e{k}:{s}"], 0.0)
+
+    def graph_edges(self, j: int, k: int, s: int):
+        if f"d{k}:{s}" not in self.staged:
+            return None
+        return {"dst": self.staged[f"d{k}:{s}"],
+                "src": self.staged[f"s{k}:{s}"],
+                "val": self.staged[f"w{k}:{s}"]}
+
+    def edge_weight_list(self, j: int, k: int, s: int):
+        return self.staged[f"{self.pre}x{k}:{s}"]
 
     def inv_deg_tile(self, j: int):
         return self.staged["deg"]
@@ -603,6 +688,13 @@ class _MeshEnv:
         t = self.pg.tiles[(j, k)][s]
         mask = t.edge_pos >= 0
         return jnp.where(mask, self.ew[np.maximum(t.edge_pos, 0)], 0.0)
+
+    def graph_edges(self, j: int, k: int, s: int):
+        return _tile_edges(self.pg, None, j, k, s)
+
+    def edge_weight_list(self, j: int, k: int, s: int):
+        eid = self.graph_edges(j, k, s)["eid"]
+        return jnp.where(eid >= 0, self.ew[np.maximum(eid, 0)], 0.0)
 
     def inv_deg_tile(self, j: int):
         return jnp.asarray(
@@ -661,6 +753,12 @@ class _AggregateKernel(_ShardKernel):
     """SpDMM-mode aggregation (paper Alg. 6): accumulate source
     sub-fibers through a destination shard's ELL tiles.
 
+    SUM and MEAN on the xla backend read a slice's edge list
+    (``ELLTile.edges``) wherever the tile carries one: its real edges
+    in destination order, not its n1 x width padded slots.  MAX/MIN
+    (masks and row flags), the Pallas kernels and ``graph_data`` tiles
+    without a list read the ELL slots.
+
     A sparsity-remapped binary (``repro.core.passes.remap``) may flip
     individual SPDMM steps to GEMM: the ELL slice is densified into an
     (n1, n1) adjacency block — cached per (j, k, s) so the fiber loop
@@ -675,6 +773,8 @@ class _AggregateKernel(_ShardKernel):
         self.op = {AggOp.SUM: "sum", AggOp.MEAN: "mean",
                    AggOp.MAX: "max", AggOp.MIN: "min"}[AggOp(lp.mode)]
         self.dyn = meta.get("edge_weight_layer") is not None
+        self.lists = (self.op in ("sum", "mean")
+                      and ex.ack.backend == "xla")
         n1, n2 = self.n1, self.n2
         self.init = (
             jnp.full((n1, n2), -3.4e38, jnp.float32) if self.op == "max"
@@ -682,24 +782,36 @@ class _AggregateKernel(_ShardKernel):
             if self.op == "min" else jnp.zeros((n1, n2), jnp.float32))
         self._dense: Dict[Tuple[int, int, int], Any] = {}
 
-    @staticmethod
-    def _live_slices(tps: List[TilePlan]) -> set:
-        """(k, s) tiles the decoded stream actually computes — after a
-        skip-empty remap this is a subset of the shard row's tiles, so
-        elided tiles are never staged either."""
-        return {(ins.args[1], ins.args[3] >> 1)
-                for tp in tps for ins in tp.compute}
+    def _live_slices(self, tps: List[TilePlan]) -> Tuple[set, set]:
+        """(k, s) tiles the decoded stream actually computes, as two
+        sets: those read as ELL slots and those read as edge lists.
+        After a skip-empty remap they cover a subset of the shard row's
+        tiles, so elided tiles are never staged either."""
+        ell, listed = set(), set()
+        for tp in tps:
+            for ins in tp.compute:
+                ks = (ins.args[1], ins.args[3] >> 1)
+                (listed if self.lists and ins.op != Opcode.GEMM
+                 else ell).add(ks)
+        return ell, listed
+
+    def _live_tiles(self, j: int, live: set):
+        for k in range(self.pg.n_blocks):
+            for s, t in enumerate(self.pg.tiles.get((j, k), [])):
+                if (k, s) in live:
+                    yield k, s, t
 
     def stage_shared(self, j, tps):
         arrs: Dict[str, Any] = {}
-        live = self._live_slices(tps)
-        for k in range(self.pg.n_blocks):
-            for s, t in enumerate(self.pg.tiles.get((j, k), [])):
-                if (k, s) not in live:
-                    continue
-                arrs[f"c{k}:{s}"] = t.cols
-                arrs[f"v{k}:{s}"] = t.vals
-                arrs[f"m{k}:{s}"] = t.edge_pos >= 0
+        ell, listed = self._live_slices(tps)
+        for k, s, t in self._live_tiles(j, ell):
+            arrs[f"c{k}:{s}"] = t.cols
+            arrs[f"v{k}:{s}"] = t.vals
+            arrs[f"m{k}:{s}"] = t.edge_pos >= 0
+        for k, s, t in self._live_tiles(j, listed):
+            arrs[f"d{k}:{s}"] = t.edges["dst"]
+            arrs[f"s{k}:{s}"] = t.edges["src"]
+            arrs[f"w{k}:{s}"] = t.edges["val"]
         if self.op == "mean":
             arrs["deg"] = np.asarray(
                 self.pg.inv_in_degree[j * self.n1:(j + 1) * self.n1])
@@ -709,11 +821,13 @@ class _AggregateKernel(_ShardKernel):
         arrs = super().stage_lane(j, tps, io, srcs)
         if self.dyn:
             ew = io["ew"]
-            live = self._live_slices(tps)
-            for k in range(self.pg.n_blocks):
-                for s, t in enumerate(self.pg.tiles.get((j, k), [])):
-                    if (k, s) in live:
-                        arrs[f"e{k}:{s}"] = ew[np.maximum(t.edge_pos, 0)]
+            ell, listed = self._live_slices(tps)
+            for k, s, t in self._live_tiles(j, ell):
+                arrs[f"e{k}:{s}"] = ew[np.maximum(t.edge_pos, 0)]
+            for k, s, t in self._live_tiles(j, listed):
+                eid = t.edges["eid"]
+                arrs[f"x{k}:{s}"] = np.where(
+                    eid >= 0, ew[np.maximum(eid, 0)], np.float32(0))
         return arrs
 
     def tile(self, tp, env):
@@ -724,6 +838,16 @@ class _AggregateKernel(_ShardKernel):
             k, ii = ins.args[1], ins.args[2]
             s, dyn = ins.args[3] >> 1, ins.args[3] & 1
             h_tile = env.h_tile(k, ii)
+            edges = (env.graph_edges(j, k, s)
+                     if self.lists and ins.op != Opcode.GEMM else None)
+            if edges is not None:
+                v = env.edge_weight_list(j, k, s) if dyn else edges["val"]
+                acc = self.ex.ack.spdmm_edges(h_tile, edges["dst"],
+                                              edges["src"], v, acc)
+                self.ex.stats.spdmm_rows += int(edges["dst"].shape[0])
+                self.ex.stats.note_mode("spdmm")
+                self.ex.stats.tile_ops += 1
+                continue
             cols, v, mask, _ = env.graph_tile(j, k, s)
             if dyn:
                 v = env.edge_weight_tile(j, k, s)
@@ -746,6 +870,7 @@ class _AggregateKernel(_ShardKernel):
             else:
                 acc, flag = self.ex.ack.spdmm(h_tile, cols, v, mask, acc,
                                               flag, self.op)
+                self.ex.stats.spdmm_rows += int(cols.size)
                 self.ex.stats.note_mode("spdmm")
             self.ex.stats.tile_ops += 1
         if self.op in ("max", "min"):
@@ -943,6 +1068,15 @@ class BinaryExecutor:
     def _residency(self, prog: CompiledProgram) -> dict:
         return resolve_residency(prog)
 
+    def _tile_fields(self, prog: CompiledProgram) -> Tuple[str, ...]:
+        """:func:`tile_fields` of the program on this executor's
+        backend, memoized on the program."""
+        memo = prog.__dict__.setdefault("_tile_fields", {})
+        if self.ack.backend not in memo:
+            memo[self.ack.backend] = tile_fields(prog.plan(),
+                                                 self.ack.backend)
+        return memo[self.ack.backend]
+
     def _begin_run(self, prog: CompiledProgram, **stats) -> None:
         """Fresh per-run stats; credits the run's skip-empty elisions
         from the remap record (the decoder drops NOPed steps, so the
@@ -986,7 +1120,7 @@ class BinaryExecutor:
         vp = pg.n_blocks * n1
         res = self._residency(prog)
         last_use = {int(k): v for k, v in res["last_use"].items()}
-        static = (pg.tile_bytes()
+        static = (pg.tile_bytes(self._tile_fields(prog))
                   + sum(_nbytes(np.asarray(w))
                         for w in prog.weights.values())
                   + _nbytes(np.asarray(pg.inv_in_degree)))
@@ -1033,10 +1167,12 @@ class BinaryExecutor:
         budget, the overshoot, and the FIRST layer step whose live set
         pushes past the budget, so a refusal is actionable.
 
-        The estimate counts what lives between layers.  A jitted layer
+        The estimate counts what lives between layers; of the tiles, the
+        arrays the program reads (:func:`tile_fields`).  A jitted layer
         (the device pass, :meth:`run_batch`) also holds XLA's own
-        temporaries while it runs, such as an SpDMM layer's gathered
-        source rows, which it leaves out."""
+        temporaries while it runs, such as an ELL slice's gathered
+        source rows (n1 x width x n2) where SpDMM reads ELL slots, which
+        it leaves out."""
         if self.resident_budget_bytes is None:
             return
         budget = self.resident_budget_bytes
@@ -1206,7 +1342,7 @@ class BinaryExecutor:
                          args={"cached": prog._plan is not None}):
             plan = prog.plan()
         if graph_data is None:
-            graph_data = device_tiles(prog.pgraph, edges=reads_edges(plan))
+            graph_data = device_tiles(prog.pgraph, self._tile_fields(prog))
         y = self._layers(prog, plan, x, graph_data,
                          weights if weights is not None else prog.weights,
                          tracer, self._layer)
@@ -1332,17 +1468,19 @@ class BinaryExecutor:
         (per-layer ``wall_s`` then times the trace); the liveness
         watermarks and hook follow the replayed layers' outputs.  After
         each pass the tracer gets the lifetime ``exec.pass_compiles`` and
-        ``exec.pass_replays`` and the pass's own ``exec.edge_scatters``."""
+        ``exec.pass_replays`` and the pass's own ``exec.edge_scatters``
+        and ``exec.spdmm_rows``."""
         self._gate_device_budget(prog, int(x.shape[1]))
         tracer = get_tracer()
         with tracer.span("decode", cat="exec", track="exec:device",
                          args={"cached": prog._plan is not None}):
             plan = prog.plan()
         pg = prog.pgraph
-        edges = reads_edges(plan)
-        gd = graph_data if graph_data is not None else device_tiles(
-            pg, edges=edges)
+        fields = self._tile_fields(prog)
+        gd = (device_tiles(pg, fields) if graph_data is None
+              else select_tiles(graph_data, fields))
         w = weights if weights is not None else prog.weights
+        edges = "epos" in fields or "edges.eid" in fields
         key = (tuple(x.shape), str(x.dtype), graph_data is not None,
                self.ack.backend, self.ack.interpret, prog.binary,
                pg.n_vertices, pg.n_edges if edges else None,
@@ -1371,6 +1509,8 @@ class BinaryExecutor:
         tracer.counter("exec.pass_replays", self.total.pass_replays,
                        track="exec:device")
         tracer.counter("exec.edge_scatters", self.stats.edge_scatters,
+                       track="exec:device")
+        tracer.counter("exec.spdmm_rows", self.stats.spdmm_rows,
                        track="exec:device")
         return y
 
@@ -1437,8 +1577,9 @@ class BinaryExecutor:
         self._gate_device_budget(prog, int(xs.shape[2]),
                                  batch=int(xs.shape[0]))
         batched = graph_data is not None
-        gd = graph_data if batched else device_tiles(
-            prog.pgraph, edges=reads_edges(prog.plan()))
+        fields = self._tile_fields(prog)
+        gd = (select_tiles(graph_data, fields) if batched
+              else device_tiles(prog.pgraph, fields))
         axes = (0, 0 if batched else None)
         if weights is not None:
             return jax.vmap(lambda x, g: self.run(

@@ -157,13 +157,16 @@ def as_graph_data(pg: PartitionedGraph) -> dict:
     """A PartitionedGraph as runtime ``graph_data`` (the executor's
     Dynasparse-style graph-as-data structure): patched live tiles can
     ride a structurally-matching program as *data* instead of being
-    bound in — the route the sampling layer's bucketed serving uses."""
+    bound in — the route the sampling layer's bucketed serving uses.
+    Each tile carries its edge list, so SUM/MEAN aggregation reads it
+    as it reads the program's own tiles."""
     tiles = {}
     for (j, k), slices in pg.tiles.items():
         for s, t in enumerate(slices):
             tiles[f"{j}:{k}:{s}"] = {
                 "cols": t.cols, "vals": t.vals,
                 "mask": t.edge_pos >= 0, "epos": t.edge_pos,
+                "edges": dict(t.edges),
             }
     return {"tiles": tiles, "inv_in_degree": pg.inv_in_degree}
 

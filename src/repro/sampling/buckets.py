@@ -19,9 +19,11 @@ absorbed at *runtime* by data-layout normalization instead:
 
 Padding is inert by construction: empty ELL slots are zero-weight
 self-referencing entries (col 0, val 0, mask off) — the blocked-ELL
-equivalent of zero-weight self-edges — and padded vertices are zero
-feature rows, so padded execution is bit-identical to the unpadded
-subgraph run (asserted end-to-end in ``tests/test_sampling.py``).
+equivalent of zero-weight self-edges — each slice's edge list holds its
+real slots in the order ``ELLTile.edges`` gives them, then zero-valued
+entries on the last row, and padded vertices are zero feature rows, so
+padded execution is bit-identical to the unpadded subgraph run
+(asserted end-to-end in ``tests/test_sampling.py``).
 
 With all requests in a bucket sharing one template graph object, the
 ``(model schema, graph signature, geometry)`` program-cache key collides
@@ -36,7 +38,8 @@ from typing import Dict
 import numpy as np
 
 from repro.core.graph import Graph
-from repro.core.passes.partition import LANE, PartitionConfig
+from repro.core.passes.partition import (LANE, PartitionConfig,
+                                         empty_edge_list, list_length)
 
 
 def next_pow2(x: int) -> int:
@@ -60,6 +63,11 @@ class Bucket:
 
     def n_blocks(self, n1: int) -> int:
         return -(-self.n_vertices // n1)
+
+    def list_length(self, n1: int) -> int:
+        """The length of every slice's edge list: room for all the
+        edges one ``n1 x width`` slice can hold, within the bucket."""
+        return list_length(min(n1 * self.width, self.n_edges))
 
 
 def bucket_for(g: Graph, cfg: PartitionConfig) -> Bucket:
@@ -140,12 +148,15 @@ def layout_graph(g: Graph, bucket: Bucket,
     Returns the ``graph_data`` structure the binary executor consumes in
     place of the program's baked tiles::
 
-        {"tiles": {"j:k:0": {"cols", "vals", "mask", "epos"}, ...},
+        {"tiles": {"j:k:0": {"cols", "vals", "mask", "epos",
+                             "edges": {"dst", "src", "val", "eid"}}, ...},
          "inv_in_degree": float32 [nb * n1]}
 
     Edge placement mirrors ``partition_graph`` exactly — (dst, src)
     sorted, per-row slots in that order, ``epos`` = original COO edge
-    index, pad slots ``epos == -1`` — so padded execution reproduces the
+    index, pad slots ``epos == -1`` — and each slice's edge list is
+    ``ELLTile.edges``'s at the bucket's one length
+    (:meth:`Bucket.list_length`), so padded execution reproduces the
     unpadded program's arithmetic bit for bit.
     """
     n1 = cfg.n1
@@ -166,6 +177,8 @@ def layout_graph(g: Graph, bucket: Bucket,
     vals = np.zeros((nb, nb, n1, w), np.float32)
     mask = np.zeros((nb, nb, n1, w), bool)
     epos = np.full((nb, nb, n1, w), -1, np.int32)
+    lists = {f: np.broadcast_to(a, (nb, nb) + a.shape).copy()
+             for f, a in empty_edge_list(n1, bucket.list_length(n1)).items()}
 
     # slot index = rank of the edge within its (dst, src-block) run,
     # computed vectorized over the (dst, src)-sorted stream.
@@ -188,10 +201,21 @@ def layout_graph(g: Graph, bucket: Bucket,
         vals[j, k, r, slot] = val
         mask[j, k, r, slot] = True
         epos[j, k, r, slot] = eid
+        # list position = rank of the edge within its (j, k) slice: a
+        # stable sort by slice keeps the (dst, src) order, row-major
+        by_tile = np.argsort(j * nb + k, kind="stable")
+        tile = (j * nb + k)[by_tile]
+        pos = np.arange(tile.size) - np.searchsorted(tile, tile)
+        jt, kt = j[by_tile], k[by_tile]
+        lists["dst"][jt, kt, pos] = r[by_tile]
+        lists["src"][jt, kt, pos] = src[by_tile] % n1
+        lists["val"][jt, kt, pos] = val[by_tile]
+        lists["eid"][jt, kt, pos] = eid[by_tile]
 
     tiles = {f"{jj}:{kk}:0": {
         "cols": cols[jj, kk], "vals": vals[jj, kk],
-        "mask": mask[jj, kk], "epos": epos[jj, kk]}
+        "mask": mask[jj, kk], "epos": epos[jj, kk],
+        "edges": {f: a[jj, kk] for f, a in lists.items()}}
         for jj in range(nb) for kk in range(nb)}
     indeg = np.bincount(g.dst, minlength=nb * n1).astype(np.float32)
     inv = (1.0 / np.maximum(indeg, 1.0)).astype(np.float32)

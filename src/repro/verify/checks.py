@@ -564,13 +564,16 @@ def check_halo_completeness(model: DefUseModel, placement: dict,
 # resident_budget
 # --------------------------------------------------------------------------- #
 def rederive_device_peak_bytes(model: DefUseModel, pgraph,
-                               weights: Dict) -> int:
+                               weights: Dict, backend: str = "xla") -> int:
     """Liveness-aware peak device bytes of a device-resident pass,
     re-derived from CSI fields + the def/use liveness — independent of
-    ``BinaryExecutor._live_profile`` (numpy-free accounting)."""
+    ``BinaryExecutor._live_profile`` (numpy-free accounting).  The
+    tiles count the arrays the pass reads on ``backend``
+    (``tile_fields``: edge lists or ELL planes)."""
     import numpy as np
+    from repro.engine.executor import tile_fields
     n1, n2, nb = model.n1, model.n2, model.nb
-    static = (pgraph.tile_bytes()
+    static = (pgraph.tile_bytes(tile_fields(model.plan, backend))
               + sum(int(np.asarray(w).size)
                     * np.asarray(w).dtype.itemsize
                     for w in weights.values())

@@ -28,7 +28,7 @@ chip_smoke = _load_script()
 # enough for several row blocks, so host streaming has shards to stream
 # under a budget below the device-resident estimate.
 TINY = dict(scale=0.01, geometry=PartitionConfig(n1=128, n2=32),
-            host_budget_bytes=4_000_000, interpret=True)
+            interpret=True)
 
 
 def _env(tmp_path, **extra):

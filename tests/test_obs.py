@@ -506,7 +506,7 @@ def test_null_tracer_anchor_is_a_noop():
 # Stable device names: the batched pass and the ACK modes' scopes.
 # --------------------------------------------------------------------------- #
 def test_batched_pass_is_named_and_scoped_by_ack_mode():
-    from repro.engine.executor import device_tiles, reads_edges
+    from repro.engine.executor import device_tiles, tile_fields
     g = _g()
     x = jnp.asarray(G.random_features(g, seed=1))
     eng = Engine(geometry=GEOM, n_pes=4)
@@ -514,7 +514,7 @@ def test_batched_pass_is_named_and_scoped_by_ack_mode():
     xs = jnp.stack([x, x])
     eng.run_batch(prog, xs)
     (fn, _), = prog.__dict__["_batch_exec"].values()
-    gd = device_tiles(prog.pgraph, edges=reads_edges(prog.plan()))
+    gd = device_tiles(prog.pgraph, tile_fields(prog.plan(), "xla"))
     text = fn.lower(xs, gd).as_text(debug_info=True)
     assert "@jit_batched_pass" in text
     assert "ack.spdmm" in text and "ack.gemm" in text
@@ -537,7 +537,9 @@ def test_tracing_leaves_tile_profile_off_and_mutes_the_jit_trace():
     counters = {e["name"]: e["args"]["value"] for e in t.events()
                 if e["ph"] == "C"}
     assert counters == {"exec.pass_compiles": 1, "exec.pass_replays": 1,
-                        "exec.edge_scatters": 0}
+                        "exec.edge_scatters": 0,
+                        "exec.spdmm_rows": eng.exec_stats.spdmm_rows}
+    assert eng.exec_stats.spdmm_rows > 0
     # per-tile dispatch keeps one decode and one span per layer
     eager = Engine(geometry=GEOM, n_pes=4, overlap=False)
     prog = eager.compile("b1", g)

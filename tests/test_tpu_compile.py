@@ -23,6 +23,7 @@ from repro.core import ack
 from repro.kernels import ops
 
 N1, W, N2 = 4096, 512, 128            # Flickr at scale 1.0, auto geometry
+M = 131072                            # edge list of its widest slice
 HBM_BYTES = 16 * 10**9                # one v5e chip
 
 
@@ -61,6 +62,8 @@ def _shapes(sharding):
         "cols": s((N1, W), jnp.int32), "vals": s((N1, W)),
         "mask": s((N1, W), jnp.bool_), "flag": s((N1,), jnp.bool_),
         "scores": s((N1, W)),
+        "dst": s((M,), jnp.int32), "src": s((M,), jnp.int32),
+        "ev": s((M,)),
     }
 
 
@@ -69,6 +72,8 @@ KERNELS = {
     "spdmm_xla_sum": (
         lambda h, c, v, m, a, f: ack._spdmm_xla(h, c, v, m, a, f, op="sum"),
         ("h", "cols", "vals", "mask", "acc", "flag"), False),
+    "spdmm_edges_xla": (ack._spdmm_edges_xla,
+                        ("h", "dst", "src", "ev", "acc"), False),
     "sddmm_pair_xla": (ack._sddmm_pair_xla,
                        ("h", "h", "cols", "mask", "scores"), False),
     "pallas_gemm": (lambda x, w: ops.gemm(x, w), ("h", "w"), True),
@@ -87,3 +92,19 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
     assert ("tpu_custom_call" in compiled.as_text()) == is_pallas
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < HBM_BYTES, mem
+
+
+def test_edge_list_kernel_temporaries_follow_the_list(one_chip,
+                                                      no_persistent_cache):
+    """SpDMM over an edge list holds [m, n2] gathered rows at most, not
+    the ELL kernel's n1 x width x n2 (1 GiB on Flickr's widest slice)."""
+    shapes = _shapes(one_chip)
+
+    def temp(fn, names):
+        return jax.jit(fn).lower(*(shapes[a] for a in names)).compile() \
+            .memory_analysis().temp_size_in_bytes
+
+    ell = temp(*KERNELS["spdmm_xla_sum"][:2])
+    edges = temp(*KERNELS["spdmm_edges_xla"][:2])
+    assert ell >= N1 * W * N2 * 4
+    assert edges <= M * N2 * 4 < N1 * W * N2 * 4
